@@ -93,6 +93,8 @@ def main(argv=None) -> None:
         p.error("an empty --sweep-batches (skip the sweep) requires "
                 "--merge: the artifact must keep its existing sweep")
 
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows = []
     table1 = crash = e2e = lf = rz = cluster = cache = obs_sec = None
     from benchmarks import (bench_cache, bench_cluster, bench_crash,

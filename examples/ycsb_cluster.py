@@ -44,7 +44,7 @@ def run_mesh(smoke: bool) -> None:
         num_shards=8)
     print(f"store: {scfg.table.num_buckets} buckets over {scfg.num_shards} "
           f"servers ({scfg.pairs_per_shard} pairs each)")
-    table = D.create_sharded(scfg)
+    table = D.create_sharded(scfg, mesh)
     lookup = D.make_lookup(scfg, mesh)
     write = D.make_write(scfg, mesh)
 

@@ -25,6 +25,7 @@ store trace for `repro.consistency`'s crash injector, and
 ``store.recover`` runs the scheme's restart procedure.
 """
 
+from repro.api.load import bulk_load
 from repro.api.registry import (available_schemes, get_scheme, make_store,
                                 register_scheme)
 from repro.api.stores import (ContinuityStore, DenseStore, LevelStore,
@@ -35,6 +36,7 @@ from repro.api.types import (CostLedger, ExecPolicy, HashStore, OpResult,
 _register_builtin(register_scheme)
 
 __all__ = [
+    "bulk_load",
     "available_schemes", "get_scheme", "make_store", "register_scheme",
     "ContinuityStore", "DenseStore", "LevelStore", "PFarmStore",
     "CostLedger", "ExecPolicy", "HashStore", "OpResult", "store_shard_axes",

@@ -282,15 +282,13 @@ class ContinuityStore(_ModuleStore):
         if self.policy.engine == "serial":
             return ch.update_serial
         return functools.partial(ch.update, probe=self.policy.mutate,
-                                 qblock=self.policy.qblock,
-                                 interpret=self.policy.interpret)
+                                 qblock=self.policy.qblock)
 
     def _delete_fn(self):
         if self.policy.engine == "serial":
             return ch.delete_serial
         return functools.partial(ch.delete, probe=self.policy.mutate,
-                                 qblock=self.policy.qblock,
-                                 interpret=self.policy.interpret)
+                                 qblock=self.policy.qblock)
 
     def _lookup_res(self, table, keys):
         if self.policy.probe == "gather":
@@ -299,8 +297,7 @@ class ContinuityStore(_ModuleStore):
         return K.probe_lookup(
             self.cfg, table, keys,
             use_kernel=self.policy.probe == "pallas",
-            interpret=self.policy.interpret, qblock=self.policy.qblock,
-            use_fp=self.policy.use_fp)
+            qblock=self.policy.qblock, use_fp=self.policy.use_fp)
 
     def _extract(self, table):
         return ch.extract_items(self.cfg, table)

@@ -76,8 +76,8 @@ class ExecPolicy:
       field — and cuts negative-search key compares, paper Figs 7/14).
       The mutation plan always filters regardless of this knob.
     * ``qblock`` — queries per Pallas grid step (probe/mutate kernels).
-    * ``interpret`` — run Pallas kernels in interpreter mode (True on CPU
-      containers; set False on real TPU hardware).
+      Whether a kernel runs compiled or interpreted is not a policy: it
+      follows the platform (`repro.kernels.platform`).
     * ``transport`` — which transport host-side drivers attach to the verb
       plans ops emit: ``"none"`` (plans price the `CostLedger` only) or
       ``"sim"`` (a `repro.rdma.RemoteMemory` endpoint with doorbell
@@ -92,7 +92,6 @@ class ExecPolicy:
     mutate: str = "gather"
     use_fp: bool = True
     qblock: int = 8
-    interpret: bool = True
     transport: str = "none"
 
     def __post_init__(self):

@@ -192,21 +192,22 @@ class ContinuityHandler(_Handler):
         for j, c in enumerate(cand):
             if c >= S:
                 if eidx >= 0:
-                    out[j] = st["ext_keys"][eidx, c - S]
+                    out[j] = st["ext_keys"][eidx, ch.slot_lanes(c - S)]
             else:
-                out[j] = st["keys"][pair, c]
+                out[j] = st["keys"][pair, ch.slot_lanes(c)]
         return out
 
     def _payload(self, cfg, op_id, pair, slot, eidx, key, val) -> PMStore:
         S = cfg.slots_per_pair
         if slot < S:
-            writes = (SubWrite("keys", (pair, slot), key),
-                      SubWrite("vals", (pair, slot), val))
+            writes = (SubWrite("keys", (pair, ch.slot_lanes(slot)), key),
+                      SubWrite("vals", (pair, ch.slot_lanes(slot)), val))
             addr = (pair * self._row_bytes(cfg) + ch.INDICATOR_BYTES
                     + ch.FP_BYTES + slot * SLOT_BYTES)
         else:
-            writes = (SubWrite("ext_keys", (eidx, slot - S), key),
-                      SubWrite("ext_vals", (eidx, slot - S), val))
+            lanes = ch.slot_lanes(slot - S)
+            writes = (SubWrite("ext_keys", (eidx, lanes), key),
+                      SubWrite("ext_vals", (eidx, lanes), val))
             addr = self._addr_ext(cfg, eidx, slot - S)
         return PMStore(op_id, "payload", False, addr, SLOT_BYTES, True, writes)
 
@@ -374,14 +375,15 @@ class ContinuityHandler(_Handler):
             ind = int(st["indicator"][p])
             for s in range(S):
                 if ind >> s & 1:
-                    out[_key_bytes(st["keys"][p, s])] = \
-                        _key_bytes(st["vals"][p, s])
+                    out[_key_bytes(st["keys"][p, ch.slot_lanes(s)])] = \
+                        _key_bytes(st["vals"][p, ch.slot_lanes(s)])
             e = int(st["ext_map"][p])
             if e >= 0:
                 for s in range(E):
                     if ind >> (S + s) & 1:
-                        out[_key_bytes(st["ext_keys"][e, s])] = \
-                            _key_bytes(st["ext_vals"][e, s])
+                        lanes = ch.slot_lanes(s)
+                        out[_key_bytes(st["ext_keys"][e, lanes])] = \
+                            _key_bytes(st["ext_vals"][e, lanes])
         for i in range(cfg.stash_slots):
             # probe priority main > ext > stash: a stash copy never shadows
             # a committed row copy (mid-relocation crash states rely on it)
@@ -412,13 +414,15 @@ class ContinuityHandler(_Handler):
         S, E = cfg.slots_per_pair, cfg.ext_slots
         ind = int(st["indicator"][pair])
         for s in range(S):
-            if ind >> s & 1 and _key_bytes(st["keys"][pair, s]) == kb:
+            if (ind >> s & 1
+                    and _key_bytes(st["keys"][pair, ch.slot_lanes(s)]) == kb):
                 return True
         e = int(st["ext_map"][pair])
         if e >= 0:
             for s in range(E):
                 if (ind >> (S + s) & 1
-                        and _key_bytes(st["ext_keys"][e, s]) == kb):
+                        and _key_bytes(st["ext_keys"][e, ch.slot_lanes(s)])
+                        == kb):
                     return True
         return False
 

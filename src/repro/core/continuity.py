@@ -67,6 +67,18 @@ FP_MASK = (1 << FP_SLOT_BITS) - 1
 _FPW = 32 // FP_SLOT_BITS            # fp fields per 32-bit lane
 STASH_CNT_SHIFT = 24                 # per-pair stash count byte (fp lane 1)
 STASH_META_BYTES = 8                 # per-stash-entry meta word (atomic commit)
+# Key and value storage is one ROW per pair (or extension group): slot s
+# holds lanes [s*KEY_LANES, (s+1)*KEY_LANES), padded to one 128-lane TPU
+# tile row.  A row is then one contiguous HBM region the probe kernel
+# fetches with a single DMA, and a slot store touches one row — a
+# (pairs, slots, lanes) array would be laid out pair-minor on the chip,
+# scattering a row across the array and making every slot store relayout
+# the whole table.
+ROW_LANES = 128
+STASH_QUERIES = 128     # ops per stash-scan chunk (see _stash_find)
+STASH_BLOCK = 32768     # stash entries per stash-scan step
+STASH_SCAN_ROW = 1024   # stash entries per free-count block (_nth_free)
+RESIDUAL_WIDTH = 64     # ops per residual-wave trip (see _residual_waves)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,6 +100,8 @@ class ContinuityConfig:
         # slot fields must fit the remaining 56 bits of the fingerprint word
         assert self.slots_per_pair * FP_SLOT_BITS <= 64 - 8, (
             f"fingerprint fields overflow the fp word: {self.slots_per_pair}")
+        # total_bits <= 32 already bounds both rows to one 128-lane tile
+        assert max(self.slots_per_pair, self.ext_slots) * KEY_LANES <= ROW_LANES
 
     # -- derived geometry ---------------------------------------------------
     @property
@@ -162,16 +176,17 @@ def _probe_order(cfg: ContinuityConfig) -> np.ndarray:
 class ContinuityTable(NamedTuple):
     """Functional table state. All arrays; geometry travels separately."""
 
-    keys: jnp.ndarray        # (P, SLOTS, KEY_LANES) uint32
-    vals: jnp.ndarray        # (P, SLOTS, VAL_LANES) uint32
+    keys: jnp.ndarray        # (P, ROW_LANES) uint32 — slot s at lanes
+    #   [s*KEY_LANES, (s+1)*KEY_LANES); `row_slots` gives the (.., S, KL) view
+    vals: jnp.ndarray        # (P, ROW_LANES) uint32, same slot lanes
     indicator: jnp.ndarray   # (P,) uint32 — one valid bit per slot (+ext bits)
     version: jnp.ndarray     # (P,) uint32 — per-pair committed-op counter; the
     #   upper half of the 8B atomic indicator word (total_bits <= 32 leaves it
     #   free), bumped by the SAME store that flips the bits.  A bare indicator
     #   word is ABA-prone (two updates can walk a key back to its slot); the
     #   counter makes (version << 32 | indicator) a safe client version stamp.
-    ext_keys: jnp.ndarray    # (PE, EXT_SLOTS, KEY_LANES) uint32
-    ext_vals: jnp.ndarray    # (PE, EXT_SLOTS, VAL_LANES) uint32
+    ext_keys: jnp.ndarray    # (PE, ROW_LANES) uint32 — one row per group
+    ext_vals: jnp.ndarray    # (PE, ROW_LANES) uint32
     ext_map: jnp.ndarray     # (P,) int32 — pair -> ext group index, -1 = none
     ext_count: jnp.ndarray   # () int32 — allocated extension groups
     count: jnp.ndarray       # () int32 — live items
@@ -187,15 +202,15 @@ class ContinuityTable(NamedTuple):
 
 
 def create(cfg: ContinuityConfig) -> ContinuityTable:
-    P, S, E, PE = cfg.num_pairs, cfg.slots_per_pair, cfg.ext_slots, cfg.ext_pool_pairs
+    P, PE = cfg.num_pairs, cfg.ext_pool_pairs
     T = max(cfg.stash_slots, 1)
     return ContinuityTable(
-        keys=jnp.zeros((P, S, KEY_LANES), U32),
-        vals=jnp.zeros((P, S, VAL_LANES), U32),
+        keys=jnp.zeros((P, ROW_LANES), U32),
+        vals=jnp.zeros((P, ROW_LANES), U32),
         indicator=jnp.zeros((P,), U32),
         version=jnp.zeros((P,), U32),
-        ext_keys=jnp.zeros((PE, E, KEY_LANES), U32),
-        ext_vals=jnp.zeros((PE, E, VAL_LANES), U32),
+        ext_keys=jnp.zeros((PE, ROW_LANES), U32),
+        ext_vals=jnp.zeros((PE, ROW_LANES), U32),
         ext_map=jnp.full((P,), -1, I32),
         ext_count=jnp.zeros((), I32),
         count=jnp.zeros((), I32),
@@ -204,6 +219,44 @@ def create(cfg: ContinuityConfig) -> ContinuityTable:
         stash_vals=jnp.zeros((T, VAL_LANES), U32),
         stash_meta=jnp.zeros((T,), U32),
     )
+
+
+def row_prefix(rows: jnp.ndarray, n: int) -> jnp.ndarray:
+    """(..., ROW_LANES) rows -> (..., n * KEY_LANES) lanes of their first n
+    slots, flat."""
+    return rows[..., :n * KEY_LANES]
+
+
+def row_slots(rows: jnp.ndarray, n: int) -> jnp.ndarray:
+    """(..., ROW_LANES) rows -> (..., n, KEY_LANES) view of their first n slots."""
+    return row_prefix(rows, n).reshape(rows.shape[:-1] + (n, KEY_LANES))
+
+
+def slot_lanes(slot: int) -> slice:
+    """Lanes of slot ``slot`` within its row, as a host-side index."""
+    return slice(slot * KEY_LANES, (slot + 1) * KEY_LANES)
+
+
+def _slot_lanes(slot: jnp.ndarray) -> jnp.ndarray:
+    """(..., KEY_LANES) lane indices of ``slot`` within its row."""
+    return (jnp.asarray(slot, I32)[..., None] * KEY_LANES
+            + jnp.arange(KEY_LANES, dtype=I32))
+
+
+def row_get(rows: jnp.ndarray, row, slot) -> jnp.ndarray:
+    """Slot payloads (..., KEY_LANES) at (row, slot)."""
+    return rows[jnp.expand_dims(row, -1), _slot_lanes(slot)]
+
+
+def _row_put(rows: jnp.ndarray, row, slot, x) -> jnp.ndarray:
+    """Store payloads ``x`` at (row, slot): KEY_LANES single-lane stores
+    per op.  Out-of-range rows are dropped (callers mask an op off by
+    passing a huge row index).  Lane-point stores, not one 4-lane window
+    per op: on the v5e the window scatter of 4096 ops into the 2^25-slot
+    table took 17 ms, the point scatter 4 ms."""
+    lanes = _slot_lanes(slot)
+    return rows.at[jnp.expand_dims(jnp.asarray(row, I32), -1), lanes].set(
+        jnp.asarray(x, rows.dtype).reshape(lanes.shape), mode="drop")
 
 
 def capacity(cfg: ContinuityConfig, table: ContinuityTable) -> jnp.ndarray:
@@ -237,22 +290,58 @@ def stash_count(table: ContinuityTable, pair: jnp.ndarray) -> jnp.ndarray:
     return (table.fp[pair, 1] >> U32(STASH_CNT_SHIFT)) & U32(0xFF)
 
 
-def _fp_store(table: ContinuityTable, ok, pair, slot, fpv) -> ContinuityTable:
-    """Set the fp field of (pair, slot) — main slots only; callers mask.
-    Active lanes must touch distinct (pair, slot); the read-modify-write
-    models the server's 4-byte fp-lane store (uncounted metadata)."""
-    w = jnp.where(ok, slot // _FPW, 0)
-    sh = (U32(FP_SLOT_BITS) * (slot % _FPW).astype(U32))
-    old = table.fp[pair, w]
-    new = (old & ~(U32(FP_MASK) << sh)) | ((fpv & U32(FP_MASK)) << sh)
+def _fp_lanes(fp: jnp.ndarray, update) -> jnp.ndarray:
+    """Rebuild the (P, 2) fp words from ``update(lane, column) -> column``.
+
+    fp writes go lane by lane on (P,) columns: a scatter whose target is
+    the (P, 2) array itself makes XLA:TPU relayout the words (a compile
+    that grows with the table and table-sized temporaries)."""
+    return jnp.stack([update(w, fp[:, w]) for w in range(2)], axis=1)
+
+
+def _fp_count_add(fp: jnp.ndarray, pair, delta: int) -> jnp.ndarray:
+    """Bump the stash count byte (fp lane 1) of ``pair`` by ``delta`` (+1
+    or -1); masked ops pass an out-of-range pair."""
+    inc = U32(1) << U32(STASH_CNT_SHIFT)
+    return _fp_lanes(fp, lambda w, col: col if w == 0 else col.at[pair].add(
+        inc if delta > 0 else -inc, mode="drop"))
+
+
+def _fp_apply(fp: jnp.ndarray, ok, pair, slot, fpv) -> jnp.ndarray:
+    """Set the fp field of (pair, slot) to ``fpv`` for every ``ok`` op
+    (main slots only; callers mask).  Ops claim pairwise-distinct
+    (pair, slot), so their 2-bit fields are disjoint and two scatter-adds
+    per lane (clear masks, then new bits) compose exactly like per-op
+    read-modify-writes of the server's 4-byte fp-lane store (uncounted
+    metadata)."""
+    P = fp.shape[0]
+    lane = slot // _FPW
+    sh = U32(FP_SLOT_BITS) * (slot % _FPW).astype(U32)
     drop = jnp.iinfo(I32).max
-    return table._replace(
-        fp=table.fp.at[jnp.where(ok, pair, drop), w].set(new, mode="drop"))
+
+    def update(w, col):
+        idx = jnp.where(ok & (lane == w), pair, drop)
+        clear = jnp.zeros((P,), U32).at[idx].add(U32(FP_MASK) << sh,
+                                                 mode="drop")
+        new = jnp.zeros((P,), U32).at[idx].add(
+            (fpv & U32(FP_MASK)) << sh, mode="drop")
+        return (col & ~clear) | new
+    return _fp_lanes(fp, update)
 
 
 # ---------------------------------------------------------------------------
 # candidate gathering — the "one contiguous segment fetch" primitive
 # ---------------------------------------------------------------------------
+
+def _cand_payload(cfg: ContinuityConfig, rows, ext_rows, pair, ext_idx, cand):
+    """(B, C, KL) payloads of each op's candidate slots in probe order: ONE
+    row fetch of the pair, plus one of its extension group."""
+    S, E = cfg.slots_per_pair, cfg.ext_slots
+    slots = row_slots(rows[pair], S)                          # (B, S, KL)
+    if E:
+        slots = jnp.concatenate([slots, row_slots(ext_rows[ext_idx], E)], 1)
+    return jnp.take_along_axis(slots, cand[..., None], 1)
+
 
 def _gather_candidates(cfg: ContinuityConfig, table: ContinuityTable,
                        pair: jnp.ndarray, parity: jnp.ndarray,
@@ -274,19 +363,13 @@ def _gather_candidates(cfg: ContinuityConfig, table: ContinuityTable,
     ind = table.indicator[pair]                      # (B,)
     bits = (ind[:, None] >> cand.astype(U32)) & U32(1)
 
-    main_ids = jnp.minimum(cand, S - 1)
-    mkeys = table.keys[pair[:, None], main_ids]      # (B, C, KL)
-    mvals = table.vals[pair[:, None], main_ids]
-
     eidx = table.ext_map[pair]                       # (B,)
     has_ext = eidx >= 0
     safe_e = jnp.maximum(eidx, 0)
-    ext_ids = jnp.maximum(cand - S, 0)
-    ekeys = table.ext_keys[safe_e[:, None], ext_ids]
-    evals = table.ext_vals[safe_e[:, None], ext_ids]
-
-    cand_keys = jnp.where(is_ext[..., None], ekeys, mkeys)
-    cand_vals = jnp.where(is_ext[..., None], evals, mvals)
+    cand_keys = _cand_payload(cfg, table.keys, table.ext_keys, pair, safe_e,
+                              cand)                  # (B, C, KL)
+    cand_vals = _cand_payload(cfg, table.vals, table.ext_vals, pair, safe_e,
+                              cand)
 
     slot_ok = jnp.where(is_ext, (has_ext | ext_allowed)[:, None], True)
     valid = (bits == 1) & slot_ok & jnp.where(is_ext, has_ext[:, None], True)
@@ -324,22 +407,73 @@ def lookup(cfg: ContinuityConfig, table: ContinuityTable,
     values = jnp.take_along_axis(cvals, first[:, None, None], 1)[:, 0]
     values = jnp.where(found[:, None], values, 0)
     found_main = jnp.any(match & ~is_ext, axis=-1)
-    found_me = found                          # matched in main or extension
     reads = 1 + (has_ext & ~found_main).astype(I32)
     if cfg.stash_slots:
-        # stash probe: the whole region arrives in one contiguous READ, so
-        # the scan is free once the fetch is paid; probe priority stays
-        # main > extension > stash (commits clear the stash entry LAST)
-        home = pair.astype(U32) + U32(1)
-        smatch = (table.stash_meta[None, :] == home[:, None]) & jnp.all(
-            table.stash_keys[None, :, :] == keys[:, None, :], axis=-1)
-        sfound = jnp.any(smatch, axis=-1) & ~found
-        sfirst = jnp.argmax(smatch, axis=-1).astype(I32)
-        values = jnp.where(sfound[:, None], table.stash_vals[sfirst], values)
-        slot = jnp.where(sfound, cfg.total_bits + sfirst, slot)
-        found = found | sfound
-        reads = reads + ((stash_count(table, pair) > 0) & ~found_me).astype(I32)
+        found, values, slot, reads = _stash_tail(cfg, table, keys, pair,
+                                                 found, values, slot, reads)
     return LookupResult(found, values, slot, pair, reads)
+
+
+def _stash_tail(cfg, table: ContinuityTable, keys, pair, found, values, slot,
+                reads):
+    """Stash stage of a lookup whose main + extension probe gave ``found``:
+    one dependent stash READ iff the pair's count byte is non-zero and the
+    key missed; probe priority stays main > extension > stash (commits
+    clear the stash entry LAST)."""
+    srd = (stash_count(table, pair) > 0) & ~found
+    sfound, sidx = _stash_find(cfg, table, keys, pair, ~found)
+    values = jnp.where(sfound[:, None], table.stash_vals[sidx], values)
+    slot = jnp.where(sfound, cfg.total_bits + sidx, slot)
+    return found | sfound, values, slot, reads + srd.astype(I32)
+
+
+def _stash_find(cfg: ContinuityConfig, table: ContinuityTable, keys, pair,
+                need):
+    """First stash entry holding each key homed at its pair, for the ops in
+    ``need``; the others report a miss.  Returns (found, sidx), (B,) each.
+
+    The stash is one shared region, so finding an entry is a scan.  Only
+    ops in ``need`` whose pair's count byte is non-zero take part (the
+    byte never reads low, so no entry is missed).  They are packed to the
+    front in batch order and compared in (STASH_QUERIES x STASH_BLOCK)
+    tiles, so memory is bounded by one tile, not by batch x stash, and a
+    batch with no such op does no scan at all."""
+    B = keys.shape[0]
+    need = need & (stash_count(table, pair) > 0)
+    T = cfg.stash_slots
+    Q, blk = min(STASH_QUERIES, B), min(STASH_BLOCK, T)
+    nq = -(-B // Q) * Q
+    drop = jnp.iinfo(I32).max
+    rank = jnp.cumsum(need.astype(I32)) - 1
+    order = jnp.full((nq,), B, I32).at[jnp.where(need, rank, drop)].set(
+        jnp.arange(B, dtype=I32), mode="drop")       # needy ops first
+    home = pair.astype(U32) + U32(1)
+    meta, skeys = table.stash_meta, table.stash_keys
+
+    def chunk(c, best):
+        q = jax.lax.dynamic_slice(order, (c * Q,), (Q,))
+        live = q < B
+        qs = jnp.minimum(q, B - 1)
+        qh, qk = home[qs], keys[qs]
+
+        def block(j, bq):
+            s0 = jnp.minimum(j * blk, T - blk)
+            m = jax.lax.dynamic_slice(meta, (s0,), (blk,))
+            k = jax.lax.dynamic_slice(skeys, (s0, 0), (blk, KEY_LANES))
+            hit = live[:, None] & (m[None, :] == qh[:, None])
+            for lane in range(KEY_LANES):
+                hit = hit & (k[None, :, lane] == qk[:, lane, None])
+            at = jnp.where(hit, s0 + jnp.arange(blk, dtype=I32), T)
+            return jnp.minimum(bq, jnp.min(at, axis=1))
+
+        bq = jax.lax.fori_loop(0, -(-T // blk), block,
+                               jnp.full((Q,), T, I32))
+        return best.at[jnp.where(live, q, drop)].set(bq, mode="drop")
+
+    n_chunks = -(-jnp.sum(need.astype(I32)) // Q)
+    best = jax.lax.fori_loop(0, n_chunks, chunk, jnp.full((B,), T, I32))
+    found = best < T
+    return found, jnp.where(found, best, 0)
 
 
 def lookup_plan(cfg: ContinuityConfig, table: ContinuityTable, keys,
@@ -450,13 +584,13 @@ def _scatter_payload(table: ContinuityTable, ok, pair, slot_id, ext_idx,
     is_ext = slot_id >= S
     m_pair = jnp.where(ok & ~is_ext, pair, jnp.iinfo(I32).max)
     m_slot = jnp.minimum(slot_id, S - 1)
-    keys = table.keys.at[m_pair, m_slot].set(key, mode="drop")
-    vals = table.vals.at[m_pair, m_slot].set(val, mode="drop")
     e_idx = jnp.where(ok & is_ext, ext_idx, jnp.iinfo(I32).max)
     e_slot = jnp.maximum(slot_id - S, 0)
-    ekeys = table.ext_keys.at[e_idx, e_slot].set(key, mode="drop")
-    evals = table.ext_vals.at[e_idx, e_slot].set(val, mode="drop")
-    return table._replace(keys=keys, vals=vals, ext_keys=ekeys, ext_vals=evals)
+    return table._replace(
+        keys=_row_put(table.keys, m_pair, m_slot, key),
+        vals=_row_put(table.vals, m_pair, m_slot, val),
+        ext_keys=_row_put(table.ext_keys, e_idx, e_slot, key),
+        ext_vals=_row_put(table.ext_vals, e_idx, e_slot, val))
 
 
 def _commit_indicator(table: ContinuityTable, ok, pair, new_word) -> ContinuityTable:
@@ -490,6 +624,26 @@ def _find_insert_slot(cfg, table, key):
     return pair[0], slot, ok, need_alloc, ext_idx
 
 
+def _nth_free(meta: jnp.ndarray, nth: jnp.ndarray):
+    """Index of the (nth+1)-th free stash entry (meta word 0), ascending,
+    for each ``nth``; and the number of free entries.
+
+    Free counts per block of STASH_SCAN_ROW entries, a search over the
+    block totals, then one row scan per query: O(T) work and O(B x row)
+    memory, where a sort or a whole-stash prefix sum compiles slowly."""
+    T = meta.shape[0]
+    blk = min(STASH_SCAN_ROW, T)
+    nb = -(-T // blk)
+    free = jnp.pad((meta == U32(0)).astype(I32), (0, nb * blk - T))
+    free = free.reshape(nb, blk)
+    upto = jnp.cumsum(jnp.sum(free, axis=1))          # free through block b
+    b = jnp.minimum(jnp.searchsorted(upto, nth + 1), nb - 1)
+    before = jnp.where(b > 0, upto[jnp.maximum(b - 1, 0)], 0)
+    row = jnp.cumsum(free[b], axis=1)                 # (B, blk)
+    j = jnp.sum(row < (nth + 1 - before)[:, None], axis=1)
+    return jnp.minimum(b * blk + j, T - 1).astype(I32), upto[-1]
+
+
 def _stash_insert_one(cfg, table: ContinuityTable, key, val, want):
     """Stash fallback of one insert (``want`` = probe failed, op active).
 
@@ -505,7 +659,7 @@ def _stash_insert_one(cfg, table: ContinuityTable, key, val, want):
     w = jnp.where(sok, sidx, drop)
     pw = jnp.where(sok, pair[0], drop)
     table = table._replace(
-        fp=table.fp.at[pw, 1].add(U32(1) << U32(STASH_CNT_SHIFT), mode="drop"),
+        fp=_fp_count_add(table.fp, pw, 1),
         stash_keys=table.stash_keys.at[w].set(key, mode="drop"),
         stash_vals=table.stash_vals.at[w].set(val, mode="drop"),
         version=table.version.at[pw].add(U32(1), mode="drop"),
@@ -528,8 +682,9 @@ def _insert_one(cfg, table: ContinuityTable, key, val, active=None):
     table = _scatter_payload(table, ok, pair, slot, ext_idx, key, val,
                              cfg.slots_per_pair)
     # fingerprint field of the NEW slot lands before the commit (main only)
-    table = _fp_store(table, ok & (slot < cfg.slots_per_pair), pair, slot,
-                      fingerprint(key[None])[0])
+    table = table._replace(fp=_fp_apply(
+        table.fp, ok & (slot < cfg.slots_per_pair), pair, slot,
+        fingerprint(key[None])[0]))
     new_word = table.indicator[pair] | jnp.where(ok, U32(1) << slot.astype(U32), U32(0))
     table = _commit_indicator(table, ok, pair, new_word)
     table = table._replace(count=table.count + ok.astype(I32))
@@ -563,8 +718,7 @@ def _delete_one(cfg, table: ContinuityTable, key, active=None):
             version=table.version.at[pw].add(U32(1), mode="drop"),
             stash_meta=table.stash_meta.at[sidx].set(U32(0), mode="drop"))
         table = table._replace(
-            fp=table.fp.at[pw, 1].add(-(U32(1) << U32(STASH_CNT_SHIFT)),
-                                      mode="drop"))
+            fp=_fp_count_add(table.fp, pw, -1))
         pm = pm + jnp.where(in_stash, 2, 0).astype(I32)
     return table._replace(count=table.count - ok.astype(I32)), ok, pm
 
@@ -595,8 +749,9 @@ def _update_one(cfg, table: ContinuityTable, key, val, active=None):
     ext_idx = jnp.maximum(table.ext_map[pair], 0)
     table = _scatter_payload(table, ok, pair, new_slot, ext_idx, key, val,
                              cfg.slots_per_pair)
-    table = _fp_store(table, ok & (new_slot < cfg.slots_per_pair), pair,
-                      new_slot, fingerprint(key[None])[0])
+    table = table._replace(fp=_fp_apply(
+        table.fp, ok & (new_slot < cfg.slots_per_pair), pair, new_slot,
+        fingerprint(key[None])[0]))
     safe_old = jnp.minimum(jnp.maximum(old_slot, 0), cfg.total_bits - 1)
     flip = jnp.where(okm, U32(1) << safe_old.astype(U32), U32(0)) | \
         (U32(1) << new_slot.astype(U32))
@@ -609,8 +764,7 @@ def _update_one(cfg, table: ContinuityTable, key, val, active=None):
         pw = jnp.where(oks, pair, drop)
         table = table._replace(
             stash_meta=table.stash_meta.at[sidx].set(U32(0), mode="drop"),
-            fp=table.fp.at[pw, 1].add(-(U32(1) << U32(STASH_CNT_SHIFT)),
-                                      mode="drop"))
+            fp=_fp_count_add(table.fp, pw, -1))
         pm = pm + jnp.where(oks, 3, 0).astype(I32)
     return table, ok, pm
 
@@ -751,6 +905,41 @@ def _plan_waves(cfg: ContinuityConfig, keys: jnp.ndarray, active: jnp.ndarray):
     return pair, parity, rank, jnp.max(rank) + 1
 
 
+def _residual_waves(cfg: ContinuityConfig, keys, unsafe, wave, state):
+    """Run the exact residual wave loop over the ``unsafe`` ops.
+
+    Waves run in rank order and each wave's ops touch pairwise-distinct
+    pairs, so a wave may be applied in any split.  Each trip takes the
+    next up-to-RESIDUAL_WIDTH ops of the current wave, compacted, so a
+    trip's gathers and scatters scale with those ops, not with the batch:
+    a hot key repeated k times costs k narrow trips.
+    ``wave(table, idx, m) -> (table, ok, pm)`` applies the ops ``idx``
+    (masked by ``m``); ``state`` is ``(table, ok, pm)`` and is returned
+    with the waves applied."""
+    B = keys.shape[0]
+    W = min(RESIDUAL_WIDTH, B)
+    _, _, rank, _ = _plan_waves(cfg, keys, unsafe)
+    rank_s, order = _stable_order(jnp.where(unsafe, rank, B), B)
+    order = jnp.concatenate([order, jnp.full((W,), B, I32)])
+    rank_s = jnp.concatenate([rank_s, jnp.full((W,), B, I32)])
+    drop = jnp.iinfo(I32).max
+
+    def body(c):
+        pos, t, ok, pm = c
+        idx = jax.lax.dynamic_slice(order, (pos,), (W,))
+        r = jax.lax.dynamic_slice(rank_s, (pos,), (W,))
+        m = (r == r[0]) & (idx < B)
+        idx = jnp.minimum(idx, B - 1)
+        t, wok, wpm = wave(t, idx, m)
+        ok = ok.at[jnp.where(m, idx, drop)].set(ok[idx] | wok, mode="drop")
+        return pos + jnp.sum(m).astype(I32), t, ok, pm + wpm
+
+    _, table, ok, pm = jax.lax.while_loop(
+        lambda c: c[0] < jnp.sum(unsafe).astype(I32), body,
+        (jnp.zeros((), I32),) + tuple(state))
+    return table, ok, pm
+
+
 @jax.custom_batching.custom_vmap
 def _pin(xs):
     """Identity that pins its operands as materialized values.
@@ -848,8 +1037,9 @@ def _insert_wave(cfg: ContinuityConfig, table: ContinuityTable, keys, vals,
         ext_map=ext_map, ext_count=table.ext_count + jnp.sum(grant).astype(I32))
     table = _scatter_payload(table, ok, pair, slot, ext_idx, keys, vals,
                              cfg.slots_per_pair)                    # phase 1
-    table = _fp_store(table, ok & (slot < cfg.slots_per_pair), pair, slot,
-                      fingerprint(keys))
+    table = table._replace(fp=_fp_apply(
+        table.fp, ok & (slot < cfg.slots_per_pair), pair, slot,
+        fingerprint(keys)))
     word = table.indicator[pair] | jnp.where(
         ok, U32(1) << slot.astype(U32), U32(0))
     table = _commit_indicator(table, ok, pair, word)                # phase 2
@@ -942,9 +1132,9 @@ def _insert_fused(cfg: ContinuityConfig, table: ContinuityTable, keys, vals,
 
     # cohort safety: per-(pair, parity) op count + spill flag, ONE scatter
     rec = jnp.where(act, 1 + (spill.astype(I32) << 16), 0)
-    cnt = jnp.zeros((P, 2), I32).at[pair_s, par_s].add(rec)
-    own = cnt[pair_s, par_s]
-    oth = cnt[pair_s, 1 - par_s]
+    cnt = jnp.zeros((2 * P,), I32).at[2 * pair_s + par_s].add(rec)
+    own = cnt[2 * pair_s + par_s]
+    oth = cnt[2 * pair_s + 1 - par_s]
     pair_empty = jax.lax.population_count(
         ~ind & U32((1 << S) - 1)).astype(I32)
     unsafe = act & (oth > 0) & (
@@ -994,40 +1184,26 @@ def _insert_fused(cfg: ContinuityConfig, table: ContinuityTable, keys, vals,
     ok, slot, eidx, pair_s, idx_s, unsafe, k_s, v_s = _pin(
         (ok, slot, eidx, pair_s, idx_s, unsafe, keys[idx_s], vals[idx_s]))
 
-    # phase 1: payload rows (flat 1-D scatters; ext rows cond-skipped)
+    # phase 1: payload rows (ext rows cond-skipped)
     is_ext = slot >= S
-    midx = jnp.where(ok & ~is_ext, pair_s * S + jnp.minimum(slot, S - 1), drop)
-    tkeys = table.keys.reshape(P * S, KEY_LANES).at[midx].set(
-        k_s, mode="drop").reshape(P, S, KEY_LANES)
-    tvals = table.vals.reshape(P * S, VAL_LANES).at[midx].set(
-        v_s, mode="drop").reshape(P, S, VAL_LANES)
+    mrow = jnp.where(ok & ~is_ext, pair_s, drop)
+    mslot = jnp.minimum(slot, S - 1)
+    tkeys = _row_put(table.keys, mrow, mslot, k_s)
+    tvals = _row_put(table.vals, mrow, mslot, v_s)
 
     def ext_rows(kv):
         ek, ev = kv
-        PE, EX = ek.shape[0], ek.shape[1]
-        eix = jnp.where(ok & is_ext,
-                        jnp.maximum(eidx, 0) * EX + jnp.maximum(slot - S, 0),
-                        drop)
-        return (ek.reshape(PE * EX, KEY_LANES).at[eix].set(
-                    k_s, mode="drop").reshape(ek.shape),
-                ev.reshape(PE * EX, VAL_LANES).at[eix].set(
-                    v_s, mode="drop").reshape(ev.shape))
+        erow = jnp.where(ok & is_ext, jnp.maximum(eidx, 0), drop)
+        eslot = jnp.maximum(slot - S, 0)
+        return _row_put(ek, erow, eslot, k_s), _row_put(ev, erow, eslot, v_s)
     tek, tev = jax.lax.cond(jnp.any(ok & is_ext), ext_rows,
                             lambda kv: kv, (table.ext_keys, table.ext_vals))
 
-    # fingerprint fields of the committed main slots: committed ops claim
-    # pairwise-distinct (pair, slot), so their 2-bit fields are disjoint
-    # and two scatter-adds (clear mask, then new bits) compose exactly like
-    # the serial path's per-op read-modify-writes
+    # fingerprint fields of the committed main slots (pairwise-distinct
+    # (pair, slot) claims, see `_fp_apply`)
     okm = ok & ~is_ext
-    fpv = fingerprint(k_s)
-    fw = jnp.where(okm, jnp.minimum(slot, S - 1) // _FPW, 0)
-    fsh = (U32(FP_SLOT_BITS) * (slot % _FPW).astype(U32))
-    fpair = jnp.where(okm, pair_s, drop)
-    fclear = jnp.zeros((P, 2), U32).at[fpair, fw].add(
-        jnp.where(okm, U32(FP_MASK) << fsh, U32(0)), mode="drop")
-    fnew = jnp.zeros((P, 2), U32).at[fpair, fw].add(
-        jnp.where(okm, (fpv & U32(FP_MASK)) << fsh, U32(0)), mode="drop")
+    fp = _fp_apply(table.fp, okm, pair_s, jnp.minimum(slot, S - 1),
+                   fingerprint(k_s))
 
     # phase 2: one-word indicator commits (bits of one pair are disjoint,
     # so a scatter-add is the batch of independent atomic ORs)
@@ -1041,8 +1217,7 @@ def _insert_fused(cfg: ContinuityConfig, table: ContinuityTable, keys, vals,
     table = table._replace(
         keys=tkeys, vals=tvals, ext_keys=tek, ext_vals=tev,
         indicator=table.indicator | add,
-        version=table.version + vadd,
-        fp=(table.fp & ~fclear) | fnew,
+        version=table.version + vadd, fp=fp,
         count=table.count + jnp.sum(ok).astype(I32))
 
     okb = jnp.zeros((B,), jnp.bool_).at[idx_s].set(ok)
@@ -1098,20 +1273,16 @@ def insert(cfg: ContinuityConfig, table: ContinuityTable, keys, vals,
         # in ascending order, exactly what the serial first-free scan picks
         def stash_pass(args):
             t, okb = args
-            T = cfg.stash_slots
             fail = active & ~okb
-            free = t.stash_meta == U32(0)
             nth = jnp.cumsum(fail.astype(I32)) - 1       # batch-order rank
-            sok = fail & (nth < jnp.sum(free.astype(I32)))
-            fs = jnp.sort(jnp.where(free, jnp.arange(T, dtype=I32), T))
-            sidx = fs[jnp.clip(nth, 0, T - 1)]
+            sidx, nfree = _nth_free(t.stash_meta, nth)
+            sok = fail & (nth < nfree)
             drop = jnp.iinfo(I32).max
             w = jnp.where(sok, sidx, drop)
             pair, _ = locate(cfg, keys)
             pw = jnp.where(sok, pair, drop)
             t = t._replace(
-                fp=t.fp.at[pw, 1].add(U32(1) << U32(STASH_CNT_SHIFT),
-                                      mode="drop"),
+                fp=_fp_count_add(t.fp, pw, 1),
                 stash_keys=t.stash_keys.at[w].set(keys, mode="drop"),
                 stash_vals=t.stash_vals.at[w].set(vals, mode="drop"),
                 version=t.version.at[pw].add(U32(1), mode="drop"),
@@ -1145,33 +1316,13 @@ def _gather_candidate_keys(cfg: ContinuityConfig, table: ContinuityTable,
     is_ext = cand >= S
     ind = table.indicator[pair]
     bits = (ind[:, None] >> cand.astype(U32)) & U32(1)
-    main_ids = jnp.minimum(cand, S - 1)
-    mkeys = table.keys[pair[:, None], main_ids]
     eidx = table.ext_map[pair]
     has_ext = eidx >= 0
-    ekeys = table.ext_keys[jnp.maximum(eidx, 0)[:, None], jnp.maximum(cand - S, 0)]
-    cand_keys = jnp.where(is_ext[..., None], ekeys, mkeys)
+    cand_keys = _cand_payload(cfg, table.keys, table.ext_keys, pair,
+                              jnp.maximum(eidx, 0), cand)
     slot_ok = jnp.where(is_ext, (has_ext | ext_allowed)[:, None], True)
     valid = (bits == 1) & slot_ok & jnp.where(is_ext, has_ext[:, None], True)
     return cand, cand_keys, valid, slot_ok
-
-
-def _stash_match(cfg, table: ContinuityTable, keys, pair):
-    """(B, T) bool: stash entries holding ``keys`` homed at ``pair``."""
-    home = pair.astype(U32) + U32(1)
-    return (table.stash_meta[None, :] == home[:, None]) & jnp.all(
-        table.stash_keys[None, :, :] == keys[:, None, :], axis=-1)
-
-
-def _stash_match_gated(cfg, table: ContinuityTable, keys, pair):
-    """`_stash_match`, skipped entirely (all-False) while no pair has a
-    live stash entry — one count-byte reduction gates the (B, T) full-key
-    compare the common stash-empty batch would otherwise pay."""
-    B = keys.shape[0]
-    return jax.lax.cond(
-        jnp.any((table.fp[:, 1] >> U32(STASH_CNT_SHIFT)) != U32(0)),
-        lambda _: _stash_match(cfg, table, keys, pair),
-        lambda _: jnp.zeros((B, cfg.stash_slots), jnp.bool_), 0)
 
 
 def _delete_wave(cfg: ContinuityConfig, table: ContinuityTable, keys,
@@ -1192,9 +1343,7 @@ def _delete_wave(cfg: ContinuityConfig, table: ContinuityTable, keys,
         # stash delete (probe priority: only when the main row missed);
         # active ops have distinct pairs, and a stash row belongs to one
         # pair, so the scatters below are conflict-free
-        smatch = _stash_match(cfg, table, keys, pair)
-        sok = m & ~ok & jnp.any(smatch, -1)
-        sidx = jnp.argmax(smatch, -1).astype(I32)
+        sok, sidx = _stash_find(cfg, table, keys, pair, m & ~ok)
         drop = jnp.iinfo(I32).max
         w = jnp.where(sok, sidx, drop)
         pw = jnp.where(sok, pair, drop)
@@ -1202,16 +1351,14 @@ def _delete_wave(cfg: ContinuityConfig, table: ContinuityTable, keys,
             version=table.version.at[pw].add(U32(1), mode="drop"),
             stash_meta=table.stash_meta.at[w].set(U32(0), mode="drop"))
         table = table._replace(
-            fp=table.fp.at[pw, 1].add(-(U32(1) << U32(STASH_CNT_SHIFT)),
-                                      mode="drop"))
+            fp=_fp_count_add(table.fp, pw, -1))
         ok = ok | sok
         pm = pm + 2 * jnp.sum(sok).astype(I32)
     return table._replace(count=table.count - jnp.sum(ok).astype(I32)), ok, pm
 
 
 def _mutation_match(cfg: ContinuityConfig, table: ContinuityTable, keys,
-                    pair, parity, *, probe="gather", qblock=8,
-                    interpret=True):
+                    pair, parity, *, probe="gather", qblock=8):
     """Pre-batch match resolution shared by the fused update/delete passes.
 
     Returns ``(found, mslot)``: the first main/extension slot (pair
@@ -1235,7 +1382,7 @@ def _mutation_match(cfg: ContinuityConfig, table: ContinuityTable, keys,
     from repro.kernels import ops as K        # deferred: pallas import
     mmain, _, _ = K.mutation_plan(cfg, table, keys,
                                   use_kernel=probe == "pallas",
-                                  interpret=interpret, qblock=qblock)
+                                  qblock=qblock)
     found_m = mmain >= 0
     S, E = cfg.slots_per_pair, cfg.ext_slots
     if E:
@@ -1243,7 +1390,7 @@ def _mutation_match(cfg: ContinuityConfig, table: ContinuityTable, keys,
         has_ext = eidx >= 0
         ebits = (table.indicator[pair][:, None]
                  >> (S + jnp.arange(E, dtype=U32))[None]) & U32(1)
-        ekeys = table.ext_keys[jnp.maximum(eidx, 0)]
+        ekeys = row_slots(table.ext_keys[jnp.maximum(eidx, 0)], E)
         ematch = has_ext[:, None] & (ebits == 1) & jnp.all(
             ekeys == keys[:, None, :], axis=-1)
         efound = jnp.any(ematch, -1)
@@ -1261,19 +1408,23 @@ def _dup_targets(cfg: ContinuityConfig, pair, cm, mslot, cs, sidx):
 
     A slot holds one key and pre-state probes of equal keys are identical,
     so duplicate targets <=> duplicate keys in the batch — the one case
-    where update/delete waves genuinely interact.  One scatter-count over
-    a flat (P * total_bits + stash) location space."""
-    P, TB, T = cfg.num_pairs, cfg.total_bits, cfg.stash_slots
-    drop = jnp.iinfo(I32).max
+    where update/delete waves genuinely interact.  One sort of the ops'
+    flat (P * total_bits + stash) locations: equal neighbours are
+    duplicates (O(B log B), nothing the size of the table)."""
+    P, TB = cfg.num_pairs, cfg.total_bits
+    B = pair.shape[0]
     loc = jnp.where(cm, pair * TB + jnp.maximum(mslot, 0),
-                    jnp.where(cs, P * TB + sidx, drop))
+                    jnp.where(cs, P * TB + sidx, jnp.iinfo(I32).max))
     hit = cm | cs
-    cnt = jnp.zeros((P * TB + max(T, 1),), I32).at[loc].add(1, mode="drop")
-    return hit & (cnt[jnp.where(hit, loc, 0)] > 1)
+    loc_s, idx = jax.lax.sort((loc, jnp.arange(B, dtype=I32)), num_keys=1)
+    same = loc_s[1:] == loc_s[:-1]
+    no = jnp.zeros((1,), jnp.bool_)
+    dup_s = jnp.concatenate([no, same]) | jnp.concatenate([same, no])
+    return hit & jnp.zeros((B,), jnp.bool_).at[idx].set(dup_s)
 
 
 def _delete_fused(cfg: ContinuityConfig, table: ContinuityTable, keys,
-                  active, *, probe, qblock, interpret):
+                  active, *, probe, qblock):
     """All delete waves fused into one pass.
 
     With distinct keys, each op's match slot comes from the PRE-batch table
@@ -1288,13 +1439,10 @@ def _delete_fused(cfg: ContinuityConfig, table: ContinuityTable, keys,
     drop = jnp.iinfo(I32).max
     pair, parity = locate(cfg, keys)
     found, mslot = _mutation_match(cfg, table, keys, pair, parity,
-                                   probe=probe, qblock=qblock,
-                                   interpret=interpret)
+                                   probe=probe, qblock=qblock)
     cm = active & found
     if cfg.stash_slots:
-        smatch = _stash_match_gated(cfg, table, keys, pair)
-        cs = active & ~found & jnp.any(smatch, -1)
-        sidx = jnp.argmax(smatch, -1).astype(I32)
+        cs, sidx = _stash_find(cfg, table, keys, pair, active & ~found)
     else:
         cs = jnp.zeros((B,), jnp.bool_)
         sidx = jnp.zeros((B,), I32)
@@ -1325,8 +1473,7 @@ def _delete_fused(cfg: ContinuityConfig, table: ContinuityTable, keys,
             w = jnp.where(oks, sidx, drop)
             pw = jnp.where(oks, pair, drop)
             return (sm.at[w].set(U32(0), mode="drop"),
-                    fp.at[pw, 1].add(-(U32(1) << U32(STASH_CNT_SHIFT)),
-                                     mode="drop"))
+                    _fp_count_add(fp, pw, -1))
         sm, fp = jax.lax.cond(jnp.any(oks), stash_tail, lambda x: x,
                               (table.stash_meta, table.fp))
         table = table._replace(stash_meta=sm, fp=fp)
@@ -1337,10 +1484,9 @@ def _delete_fused(cfg: ContinuityConfig, table: ContinuityTable, keys,
 
 
 @functools.partial(jax.jit, static_argnums=0,
-                   static_argnames=("probe", "qblock", "interpret"))
+                   static_argnames=("probe", "qblock"))
 def delete(cfg: ContinuityConfig, table: ContinuityTable, keys, mask=None,
-           *, probe: str = "gather", qblock: int = 8,
-           interpret: bool = True):
+           *, probe: str = "gather", qblock: int = 8):
     """Server-side batched delete on the wave engine. 1 PM write/op
     (2 for stash entries).
 
@@ -1350,22 +1496,16 @@ def delete(cfg: ContinuityConfig, table: ContinuityTable, keys, mask=None,
     ``probe`` selects the match backend (see `_mutation_match`)."""
     keys, _, active = _batch_arrays(keys, mask=mask)
     table, ok, pm, unsafe = _delete_fused(cfg, table, keys, active,
-                                          probe=probe, qblock=qblock,
-                                          interpret=interpret)
+                                          probe=probe, qblock=qblock)
 
     # residual wave loop: ranks are planned over the UNSAFE ops alone, so
     # the trip count is bounded by the contended cohorts (zero trips — the
     # loop body never executes — for the common duplicate-free batch)
-    pair, parity, rank, num_waves = _plan_waves(cfg, keys, unsafe)
-
-    def body(c):
-        w, t, okw, pmw = c
-        t, wok, wpm = _delete_wave(cfg, t, keys, pair, parity, rank == w)
-        return w + 1, t, okw | wok, pmw + wpm
-
-    _, table, ok, pm = jax.lax.while_loop(
-        lambda c: c[0] < num_waves, body,
-        (jnp.zeros((), I32), table, ok, pm))
+    pair, parity = locate(cfg, keys)
+    table, ok, pm = _residual_waves(
+        cfg, keys, unsafe,
+        lambda t, i, m: _delete_wave(cfg, t, keys[i], pair[i], parity[i], m),
+        (table, ok, pm))
     ctr = pmem.CostLedger.zero().add(pm_writes=pm, ops=jnp.sum(active))
     return table, ok, ctr
 
@@ -1383,9 +1523,7 @@ def _update_wave(cfg: ContinuityConfig, table: ContinuityTable, keys, vals,
     new = jnp.take_along_axis(cand, jnp.argmax(empty, -1)[:, None], 1)[:, 0]
     has_empty = jnp.any(empty, -1)
     if cfg.stash_slots:
-        smatch = _stash_match(cfg, table, keys, pair)
-        in_stash = ~found & jnp.any(smatch, -1)
-        sidx = jnp.argmax(smatch, -1).astype(I32)
+        in_stash, sidx = _stash_find(cfg, table, keys, pair, m & ~found)
         found = found | in_stash
     else:
         in_stash = jnp.zeros((B,), jnp.bool_)
@@ -1397,8 +1535,9 @@ def _update_wave(cfg: ContinuityConfig, table: ContinuityTable, keys, vals,
     ok, okm, oks, old, new, ext_idx = _pin((ok, okm, oks, old, new, ext_idx))
     table = _scatter_payload(table, ok, pair, new, ext_idx, keys, vals,
                              cfg.slots_per_pair)                    # phase 1
-    table = _fp_store(table, ok & (new < cfg.slots_per_pair), pair, new,
-                      fingerprint(keys))
+    table = table._replace(fp=_fp_apply(
+        table.fp, ok & (new < cfg.slots_per_pair), pair, new,
+        fingerprint(keys)))
     flip = jnp.where(okm, U32(1) << jnp.maximum(old, 0).astype(U32), U32(0)) \
         | (U32(1) << new.astype(U32))
     word = table.indicator[pair] ^ jnp.where(ok, flip, U32(0))
@@ -1412,14 +1551,13 @@ def _update_wave(cfg: ContinuityConfig, table: ContinuityTable, keys, vals,
         pw = jnp.where(oks, pair, drop)
         table = table._replace(
             stash_meta=table.stash_meta.at[w].set(U32(0), mode="drop"),
-            fp=table.fp.at[pw, 1].add(-(U32(1) << U32(STASH_CNT_SHIFT)),
-                                      mode="drop"))
+            fp=_fp_count_add(table.fp, pw, -1))
         pm = pm + 3 * jnp.sum(oks).astype(I32)
     return table, ok, pm
 
 
 def _update_fused(cfg: ContinuityConfig, table: ContinuityTable, keys, vals,
-                  active, *, probe, qblock, interpret):
+                  active, *, probe, qblock):
     """All update waves fused into one rank-indexed pass.
 
     With distinct keys, each op's OLD slot is fixed by the pre-batch table
@@ -1442,12 +1580,10 @@ def _update_fused(cfg: ContinuityConfig, table: ContinuityTable, keys, vals,
     drop = jnp.iinfo(I32).max
     pair, parity = locate(cfg, keys)
     found, mslot = _mutation_match(cfg, table, keys, pair, parity,
-                                   probe=probe, qblock=qblock,
-                                   interpret=interpret)
+                                   probe=probe, qblock=qblock)
     if cfg.stash_slots:
-        smatch = _stash_match_gated(cfg, table, keys, pair)
-        in_stash = ~found & jnp.any(smatch, -1)
-        sidx = jnp.argmax(smatch, -1).astype(I32)
+        in_stash, sidx = _stash_find(cfg, table, keys, pair,
+                                       active & ~found)
     else:
         in_stash = jnp.zeros((B,), jnp.bool_)
         sidx = jnp.zeros((B,), I32)
@@ -1497,57 +1633,32 @@ def _update_fused(cfg: ContinuityConfig, table: ContinuityTable, keys, vals,
     ok, okm, oks, new_slot, eidx, evo = _pin(
         (ok, okm, oks, new_slot, eidx, evo))
 
-    # phase 1: payload rows (ONE flat scatter covers keys and values —
-    # key rows in [0, P*S), value rows in [P*S, 2*P*S); ext rows
-    # cond-skipped)
+    # phase 1: payload rows (ext rows cond-skipped)
     is_ext = new_slot >= S
-    okp = ok & ~is_ext
-    slotf = pair * S + jnp.minimum(new_slot, S - 1)
-    pay = jnp.concatenate([table.keys.reshape(P * S, KEY_LANES),
-                           table.vals.reshape(P * S, VAL_LANES)]).at[
-        jnp.concatenate([jnp.where(okp, slotf, drop),
-                         jnp.where(okp, slotf + P * S, drop)])].set(
-        jnp.concatenate([keys, vals]), mode="drop")
-    tkeys = pay[:P * S].reshape(P, S, KEY_LANES)
-    tvals = pay[P * S:].reshape(P, S, VAL_LANES)
+    mrow = jnp.where(ok & ~is_ext, pair, drop)
+    mslot_new = jnp.minimum(new_slot, S - 1)
+    tkeys = _row_put(table.keys, mrow, mslot_new, keys)
+    tvals = _row_put(table.vals, mrow, mslot_new, vals)
 
     def ext_rows(kv):
         ek, ev = kv
-        PE, EX = ek.shape[0], ek.shape[1]
-        eix = jnp.where(ok & is_ext,
-                        eidx * EX + jnp.maximum(new_slot - S, 0), drop)
-        return (ek.reshape(PE * EX, KEY_LANES).at[eix].set(
-                    keys, mode="drop").reshape(ek.shape),
-                ev.reshape(PE * EX, VAL_LANES).at[eix].set(
-                    vals, mode="drop").reshape(ev.shape))
+        erow = jnp.where(ok & is_ext, eidx, drop)
+        eslot = jnp.maximum(new_slot - S, 0)
+        return _row_put(ek, erow, eslot, keys), _row_put(ev, erow, eslot, vals)
     tek, tev = jax.lax.cond(jnp.any(ok & is_ext), ext_rows,
                             lambda kv: kv, (table.ext_keys, table.ext_vals))
 
-    # fingerprint fields of the claimed slots (disjoint 2-bit fields) and
-    # the per-pair version bumps: ONE flat scatter-add carries all three
-    # side words (version bumps in [0,P), fp clear masks in [P,3P), fp new
-    # fields in [3P,5P)) — scatter dispatch dominates this pass on CPU
-    okf = ok & ~is_ext
-    fpv = fingerprint(keys)
-    fw = jnp.minimum(new_slot, S - 1) // _FPW
-    fsh = U32(FP_SLOT_BITS) * (new_slot % _FPW).astype(U32)
-    fflat = pair * 2 + fw
-    sidxs = jnp.concatenate([jnp.where(ok, pair, drop),
-                             jnp.where(okf, P + fflat, drop),
-                             jnp.where(okf, 3 * P + fflat, drop)])
-    supd = jnp.concatenate([jnp.ones((B,), U32),
-                            U32(FP_MASK) << fsh,
-                            (fpv & U32(FP_MASK)) << fsh])
-    buf = jnp.zeros((5 * P,), U32).at[sidxs].add(supd, mode="drop")
-    vadd, fclear, fnew = (buf[:P], buf[P:3 * P].reshape(P, 2),
-                          buf[3 * P:].reshape(P, 2))
+    # fingerprint fields of the claimed slots (disjoint 2-bit fields, see
+    # `_fp_apply`) and the per-pair version bumps
+    fp = _fp_apply(table.fp, ok & ~is_ext, pair, mslot_new, fingerprint(keys))
+    vadd = jnp.zeros((P,), U32).at[jnp.where(ok, pair, drop)].add(
+        U32(1), mode="drop")
 
     # phase 2: indicator words straight from the evolved copy (equal to the
     # serial per-op XOR chain), version bumps as per-pair sums
     table = table._replace(
         keys=tkeys, vals=tvals, ext_keys=tek, ext_vals=tev,
-        indicator=evo, version=table.version + vadd,
-        fp=(table.fp & ~fclear) | fnew)
+        indicator=evo, version=table.version + vadd, fp=fp)
     pm = 2 * jnp.sum(okm).astype(I32)
     if cfg.stash_slots:
         # stash relocation tail (commit first: the main copy wins by probe
@@ -1558,8 +1669,7 @@ def _update_fused(cfg: ContinuityConfig, table: ContinuityTable, keys, vals,
             w = jnp.where(oks, sidx, drop)
             pw = jnp.where(oks, pair, drop)
             return (sm.at[w].set(U32(0), mode="drop"),
-                    fp.at[pw, 1].add(-(U32(1) << U32(STASH_CNT_SHIFT)),
-                                     mode="drop"))
+                    _fp_count_add(fp, pw, -1))
         sm, fp = jax.lax.cond(jnp.any(oks), stash_tail, lambda x: x,
                               (table.stash_meta, table.fp))
         table = table._replace(stash_meta=sm, fp=fp)
@@ -1568,10 +1678,9 @@ def _update_fused(cfg: ContinuityConfig, table: ContinuityTable, keys, vals,
 
 
 @functools.partial(jax.jit, static_argnums=0,
-                   static_argnames=("probe", "qblock", "interpret"))
+                   static_argnames=("probe", "qblock"))
 def update(cfg: ContinuityConfig, table: ContinuityTable, keys, vals,
-           mask=None, *, probe: str = "gather", qblock: int = 8,
-           interpret: bool = True):
+           mask=None, *, probe: str = "gather", qblock: int = 8):
     """Server-side batched out-of-place update on the wave engine.
     2 PM writes/op; both bit-flips land in ONE atomic indicator store
     (3 writes when the op relocates a stash entry into the main row).
@@ -1583,23 +1692,17 @@ def update(cfg: ContinuityConfig, table: ContinuityTable, keys, vals,
     (see `_mutation_match`)."""
     keys, vals, active = _batch_arrays(keys, vals, mask)
     table, ok, pm, unsafe = _update_fused(cfg, table, keys, vals, active,
-                                          probe=probe, qblock=qblock,
-                                          interpret=interpret)
+                                          probe=probe, qblock=qblock)
 
     # residual wave loop: ranks are planned over the UNSAFE (duplicate-
     # target-pair) ops alone, so the trip count is bounded by the
     # contended cohorts — zero trips for the common duplicate-free batch
-    pair, parity, rank, num_waves = _plan_waves(cfg, keys, unsafe)
-
-    def body(c):
-        w, t, okw, pmw = c
-        t, wok, wpm = _update_wave(cfg, t, keys, vals, pair, parity,
-                                   rank == w)
-        return w + 1, t, okw | wok, pmw + wpm
-
-    _, table, ok, pm = jax.lax.while_loop(
-        lambda c: c[0] < num_waves, body,
-        (jnp.zeros((), I32), table, ok, pm))
+    pair, parity = locate(cfg, keys)
+    table, ok, pm = _residual_waves(
+        cfg, keys, unsafe,
+        lambda t, i, m: _update_wave(cfg, t, keys[i], vals[i], pair[i],
+                                     parity[i], m),
+        (table, ok, pm))
     ctr = pmem.CostLedger.zero().add(pm_writes=pm, ops=jnp.sum(active))
     return table, ok, ctr
 
@@ -1631,8 +1734,8 @@ def extract_items(cfg: ContinuityConfig, table: ContinuityTable):
     """All live (key, value) slots as flat arrays + validity mask (jittable)."""
     P, S, E = cfg.num_pairs, cfg.slots_per_pair, cfg.ext_slots
     bits = (table.indicator[:, None] >> jnp.arange(S, dtype=U32)[None]) & U32(1)
-    mkeys = table.keys.reshape(P * S, KEY_LANES)
-    mvals = table.vals.reshape(P * S, VAL_LANES)
+    mkeys = row_slots(table.keys, S).reshape(P * S, KEY_LANES)
+    mvals = row_slots(table.vals, S).reshape(P * S, VAL_LANES)
     mmask = (bits == 1).reshape(P * S)
     ebits = (table.indicator[:, None] >> (S + jnp.arange(E, dtype=U32))[None]) & U32(1)
     has = table.ext_map >= 0
@@ -1641,8 +1744,8 @@ def extract_items(cfg: ContinuityConfig, table: ContinuityTable):
     pool_mask = jnp.zeros((PE, E), jnp.bool_).at[
         jnp.where(has, table.ext_map, PE), :].set(
         (ebits == 1) & has[:, None], mode="drop")
-    ekeys = table.ext_keys.reshape(PE * E, KEY_LANES)
-    evals = table.ext_vals.reshape(PE * E, VAL_LANES)
+    ekeys = row_slots(table.ext_keys, E).reshape(PE * E, KEY_LANES)
+    evals = row_slots(table.ext_vals, E).reshape(PE * E, VAL_LANES)
     keys = jnp.concatenate([mkeys, ekeys], 0)
     vals = jnp.concatenate([mvals, evals], 0)
     mask = jnp.concatenate([mmask, pool_mask.reshape(PE * E)], 0)
@@ -1761,14 +1864,14 @@ def cohort_items(cfg: ContinuityConfig, table: ContinuityTable, pair):
     pair = jnp.asarray(pair, I32)
     ind = table.indicator[pair]
     mmask = ((ind >> jnp.arange(S, dtype=U32)) & U32(1)) == 1
-    keys = table.keys[pair]
-    vals = table.vals[pair]
     eidx = table.ext_map[pair]
     ebits = ((ind >> (U32(S) + jnp.arange(E, dtype=U32))) & U32(1)) == 1
     emask = ebits & (eidx >= 0)
     safe_e = jnp.maximum(eidx, 0)
-    keys = jnp.concatenate([keys, table.ext_keys[safe_e]], 0)
-    vals = jnp.concatenate([vals, table.ext_vals[safe_e]], 0)
+    keys = jnp.concatenate([row_slots(table.keys[pair], S),
+                            row_slots(table.ext_keys[safe_e], E)], 0)
+    vals = jnp.concatenate([row_slots(table.vals[pair], S),
+                            row_slots(table.ext_vals[safe_e], E)], 0)
     mask = jnp.concatenate([mmask, emask], 0)
     if T:
         smask = table.stash_meta == pair.astype(U32) + U32(1)

@@ -29,8 +29,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import continuity as ch
 from repro.core import pmem
@@ -54,8 +53,8 @@ class StoreConfig:
 
     def __post_init__(self):
         assert self.table.num_pairs % self.num_shards == 0
-        assert self.table.ext_frac == 0.0, \
-            "distributed store uses ext-free tables (DESIGN.md §5)"
+        assert self.table.ext_frac == 0.0 and self.table.stash_frac == 0.0, \
+            "distributed store uses ext- and stash-free tables (DESIGN.md §5)"
 
     @property
     def pairs_per_shard(self) -> int:
@@ -71,9 +70,12 @@ class StoreConfig:
         return min(c, batch_per_shard)
 
 
-def create_sharded(cfg: StoreConfig) -> ContinuityTable:
-    """Global table as one pytree; shard dim 0 (pairs) over 'data'."""
-    return ch.create(cfg.table)
+def create_sharded(cfg: StoreConfig, mesh) -> ContinuityTable:
+    """Global table as one pytree, created already sharded: each device
+    materializes only its own pair range (dim 0 over the store axes)."""
+    shardings = jax.tree.map(lambda spec: NamedSharding(mesh, spec),
+                             table_pspec(cfg.axis_names))
+    return jax.jit(lambda: ch.create(cfg.table), out_shardings=shardings)()
 
 
 def table_pspec(axes=("data",)) -> ContinuityTable:
@@ -175,20 +177,16 @@ def make_lookup(cfg: StoreConfig, mesh):
 
         # owner side: fetch raw segment payload (NO probing — one-sided read)
         lp = jnp.maximum(recv[..., 0].astype(I32) % Ppairs, 0)
-        seg_k = table.keys[lp]                          # (S, CAP, SL, KL)
-        seg_v = table.vals[lp]
+        seg_k = ch.row_prefix(table.keys[lp], SL)       # (S, CAP, SL*KL)
+        seg_v = ch.row_prefix(table.vals[lp], SL)
         ind = table.indicator[lp]                       # (S, CAP)
-        reply = jnp.concatenate([
-            seg_k.reshape(*lp.shape, SL * KEY_LANES).astype(U32),
-            seg_v.reshape(*lp.shape, SL * VAL_LANES).astype(U32),
-            ind[..., None].astype(U32)], -1)
+        reply = jnp.concatenate([seg_k, seg_v, ind[..., None]], -1)
         out, ok = _route_back(cfg, reply, meta)
 
         # client side: local probe of the fetched segment
         B = keys.shape[0]
-        rkeys = out[:, :SL * KEY_LANES].reshape(B, SL, KEY_LANES)
-        rvals = out[:, SL * KEY_LANES:SL * (KEY_LANES + VAL_LANES)] \
-            .reshape(B, SL, VAL_LANES)
+        rkeys = ch.row_slots(out, SL)                   # (B, SL, KL)
+        rvals = ch.row_slots(out[:, seg_k.shape[-1]:], SL)
         rind = out[:, -1]
         found, vals = _client_probe(cfg.table, rkeys, rvals, rind, parity,
                                     keys, ok)
@@ -207,12 +205,12 @@ def make_lookup(cfg: StoreConfig, mesh):
         return DLookupResult(found, vals, ok, ledger)
 
     ax = P(cfg.axis_names)
-    sm = shard_map(impl, mesh=mesh,
-                   in_specs=(table_pspec(cfg.axis_names), ax, ax),
-                   out_specs=DLookupResult(
-                       ax, ax, ax,
-                       pmem.CostLedger(P(), P(), P(), P())),
-                   check_rep=False)
+    sm = jax.shard_map(impl, mesh=mesh,
+                       in_specs=(table_pspec(cfg.axis_names), ax, ax),
+                       out_specs=DLookupResult(
+                           ax, ax, ax,
+                           pmem.CostLedger(P(), P(), P(), P())),
+                       check_vma=False)
     jitted = jax.jit(sm)
 
     def lookup(table, keys, mask=None):
@@ -299,10 +297,10 @@ def make_write(cfg: StoreConfig, mesh):
         return table, (out[:, 0] == 1) & ok, ok
 
     ax = P(cfg.axis_names)
-    sm = shard_map(impl, mesh=mesh,
-                   in_specs=(table_pspec(cfg.axis_names), ax, ax, ax),
-                   out_specs=(table_pspec(cfg.axis_names), ax, ax),
-                   check_rep=False)
+    sm = jax.shard_map(impl, mesh=mesh,
+                       in_specs=(table_pspec(cfg.axis_names), ax, ax, ax),
+                       out_specs=(table_pspec(cfg.axis_names), ax, ax),
+                       check_vma=False)
     return jax.jit(sm, donate_argnums=0)
 
 
@@ -336,24 +334,23 @@ def make_lookup_multifetch(cfg: StoreConfig, mesh, fetches: int = 4):
             req = pair[:, None].astype(U32)
             recv, rlive, meta = _route(cfg, req, owner, mask)
             lp = jnp.maximum(recv[..., 0].astype(I32) % Ppairs, 0)
-            rowk = table.keys[lp][..., :SL // 4, :]
-            rowv = table.vals[lp][..., :SL // 4, :]
+            rowk = ch.row_prefix(table.keys[lp], SL // 4)
+            rowv = ch.row_prefix(table.vals[lp], SL // 4)
             reply = jnp.concatenate(
-                [rowk.reshape(*lp.shape, -1), rowv.reshape(*lp.shape, -1),
-                 table.indicator[lp][..., None]], -1).astype(U32)
+                [rowk, rowv, table.indicator[lp][..., None]], -1)
             out, ok = _route_back(cfg, reply, meta)
             reps.append((out, ok))
         found = jnp.zeros((B,), jnp.bool_)
         for out, ok in reps:     # client-side probe of each fetched bucket
-            rk = out[:, :SL // 4 * KEY_LANES].reshape(B, SL // 4, KEY_LANES)
+            rk = ch.row_slots(out, SL // 4)
             hit = jnp.any(jnp.all(rk == keys[:, None, :], -1), -1) & ok
             found = found | hit
         return found
 
     ax = P(cfg.axis_names)
-    sm = shard_map(impl, mesh=mesh,
-                   in_specs=(table_pspec(cfg.axis_names), ax, ax),
-                   out_specs=ax, check_rep=False)
+    sm = jax.shard_map(impl, mesh=mesh,
+                       in_specs=(table_pspec(cfg.axis_names), ax, ax),
+                       out_specs=ax, check_vma=False)
     jitted = jax.jit(sm)
 
     def lookup(table, keys, mask=None):

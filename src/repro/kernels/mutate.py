@@ -18,9 +18,8 @@ host-side fused pass consumes the match side directly and replays victim
 allocation only for pairs that receive multiple ops in one batch (the
 plan's victim is pre-state-exact for the single-op-per-pair common case).
 
-DMA/grid structure is identical to ``probe.py``: ``qblock`` queries per
-grid step, all row copies started before any wait, all plan math one
-vectorized (Q, S) VPU pass.  The fingerprint filter is ALWAYS on here —
+The kernel body, DMA structure and layout are ``probe.py``'s (one shared
+`probe._segment_kernel`); the fingerprint filter is ALWAYS on here —
 mutations must never act on a wrong slot, and visible slots always carry
 the correct field, so the filter is a pure compare-reduction.
 """
@@ -30,116 +29,18 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-U32 = jnp.uint32
-I32 = jnp.int32
-BIG = 0x7FFFFFFF  # python int: stays a kernel-embedded literal
+from repro.kernels.probe import segment_call
 
 
-def _mutate_kernel(pairs_ref, rows_ref, ind_ref, fps_ref, prio_ref,
-                   parity_ref, qk_ref, qfp_ref, match_ref, victim_ref,
-                   flip_ref, seg_vmem, ind_vmem, fp_vmem, sem, *,
-                   slots: int, key_lanes: int, qblock: int):
-    i = pl.program_id(0)
-
-    # ONE contiguous DMA per query: segment row + the indicator and fp
-    # words that physically head the same region.  All copies start before
-    # any wait (doorbell batching) — see probe.py for the layout notes.
-    def start(q, carry):
-        p = pairs_ref[i * qblock + q]
-        pltpu.make_async_copy(rows_ref.at[p], seg_vmem.at[q], sem).start()
-        pltpu.make_async_copy(ind_ref.at[p], ind_vmem.at[q], sem).start()
-        pltpu.make_async_copy(fps_ref.at[p], fp_vmem.at[q], sem).start()
-        return carry
-
-    def wait(q, carry):
-        p = pairs_ref[i * qblock + q]
-        pltpu.make_async_copy(rows_ref.at[p], seg_vmem.at[q], sem).wait()
-        pltpu.make_async_copy(ind_ref.at[p], ind_vmem.at[q], sem).wait()
-        pltpu.make_async_copy(fps_ref.at[p], fp_vmem.at[q], sem).wait()
-        return carry
-
-    jax.lax.fori_loop(0, qblock, start, 0)
-    jax.lax.fori_loop(0, qblock, wait, 0)
-
-    seg = seg_vmem[...].reshape(qblock, slots, key_lanes)
-    qk = qk_ref[...]                                          # (Q, KL)
-    eq = jnp.all(seg == qk[:, None, :], axis=-1)              # (Q, S)
-    iota = jax.lax.broadcasted_iota(U32, (qblock, slots), 1)
-    bits = (ind_vmem[...] >> iota) & U32(1)                   # (Q,1)>>(Q,S)
-    lane = jnp.where(iota < U32(16), fp_vmem[:, 0:1], fp_vmem[:, 1:2])
-    field = (lane >> (U32(2) * (iota % U32(16)))) & U32(3)    # (Q, S)
-    eq = eq & (field == qfp_ref[...])                         # fp pre-filter
-    pr = jnp.where(parity_ref[...] == 0,
-                   prio_ref[0][None, :], prio_ref[1][None, :])  # (Q, S)
-    cand = pr < BIG
-    mrank = jnp.where(eq & (bits == U32(1)) & cand, pr, BIG)
-    vrank = jnp.where((bits == U32(0)) & cand, pr, BIG)
-    mslot = jnp.argmin(mrank, axis=-1).astype(I32)
-    vslot = jnp.argmin(vrank, axis=-1).astype(I32)
-    mfound = jnp.min(mrank, -1) < BIG
-    vfound = jnp.min(vrank, -1) < BIG
-    match_ref[...] = jnp.where(mfound, mslot, -1)[:, None]
-    victim_ref[...] = jnp.where(vfound, vslot, -1)[:, None]
-    flip_ref[...] = (jnp.where(mfound, U32(1) << mslot.astype(U32), U32(0))
-                     | jnp.where(vfound, U32(1) << vslot.astype(U32),
-                                 U32(0)))[:, None]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "qblock"))
+@functools.partial(jax.jit, static_argnames=("qblock",))
 def mutate_segments(rows, indicators, fps, prio, pairs, parity, qkeys, qfp,
-                    *, interpret: bool = True, qblock: int = 8):
+                    *, qblock: int = 8):
     """Resolve the mutation plan for one contiguous segment row per query.
 
     Args mirror ``probe.probe_segments`` with the fp word mandatory.
     Returns ``(match_slot, victim_slot, flip)``: (B,) int32/int32/uint32
     with -1 for miss/full and ``flip`` the one-word commit XOR mask.
     """
-    P, RL = rows.shape
-    B, KL = qkeys.shape
-    S = RL // KL
-    nb = max(1, -(-B // qblock))
-    pad = nb * qblock - B
-    pairs = jnp.pad(pairs.astype(I32), (0, pad))
-    parity = jnp.pad(parity.astype(I32), (0, pad))[:, None]
-    qkeys = jnp.pad(qkeys, ((0, pad), (0, 0)))
-    qfp = jnp.pad(qfp.astype(U32), (0, pad))[:, None]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                     # pairs drive the row DMAs
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),     # rows stay in HBM
-            pl.BlockSpec(memory_space=pl.ANY),     # indicators stay in HBM
-            pl.BlockSpec(memory_space=pl.ANY),     # fp words stay in HBM
-            pl.BlockSpec((2, S), lambda i, pairs: (0, 0)),
-            pl.BlockSpec((qblock, 1), lambda i, pairs: (i, 0)),
-            pl.BlockSpec((qblock, KL), lambda i, pairs: (i, 0)),
-            pl.BlockSpec((qblock, 1), lambda i, pairs: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((qblock, 1), lambda i, pairs: (i, 0)),
-            pl.BlockSpec((qblock, 1), lambda i, pairs: (i, 0)),
-            pl.BlockSpec((qblock, 1), lambda i, pairs: (i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((qblock, RL), U32),         # per-block segment tile
-            pltpu.VMEM((qblock, 1), U32),          # per-block indicators
-            pltpu.VMEM((qblock, 2), U32),          # per-block fp words
-            pltpu.SemaphoreType.DMA(()),
-        ],
-    )
-    match, victim, flip = pl.pallas_call(
-        functools.partial(_mutate_kernel, slots=S, key_lanes=KL,
-                          qblock=qblock),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((nb * qblock, 1), I32),
-            jax.ShapeDtypeStruct((nb * qblock, 1), I32),
-            jax.ShapeDtypeStruct((nb * qblock, 1), U32),
-        ],
-        interpret=interpret,
-    )(pairs, rows, indicators, fps, prio, parity, qkeys, qfp)
-    return match[:B, 0], victim[:B, 0], flip[:B, 0]
+    return segment_call(rows, indicators, prio, pairs, parity, qkeys, fps,
+                        qfp, qblock=qblock, emit_flip=True)

@@ -18,13 +18,12 @@ BIG = 0x7FFFFFFF
 
 def mutate_ref(rows, indicators, fps, prio, pairs, parity, qkeys, qfp):
     """Returns (match_slot, victim_slot, flip); see mutate.mutate_segments."""
-    P, RL = rows.shape
     B, KL = qkeys.shape
-    S = RL // KL
-    seg = rows[pairs].reshape(B, S, KL)
+    S = prio.shape[1]
+    seg = rows[pairs][:, :S * KL].reshape(B, S, KL)
     eq = jnp.all(seg == qkeys[:, None, :], axis=-1)           # (B, S)
     iota = jnp.arange(S, dtype=U32)[None, :]
-    bits = (indicators[pairs] >> iota) & U32(1)               # (B, S)
+    bits = (indicators.reshape(-1)[pairs][:, None] >> iota) & U32(1)  # (B, S)
     lane = jnp.where(iota < U32(16), fps[pairs, 0:1], fps[pairs, 1:2])
     field = (lane >> (U32(2) * (iota % U32(16)))) & U32(3)
     eq = eq & (field == qfp.astype(U32)[:, None])             # fp pre-filter

@@ -9,16 +9,16 @@ the continuity backend's kernel probe strategy, selected through
 ``paged_attention`` is re-exported with TPU-alignment padding for the
 q-head-group dimension.
 
-Set ``interpret=False`` on real TPU hardware; this container is CPU-only so
-every caller (tests, benches) uses the interpreter, which executes the same
-kernel body.
+The kernels read the table's own row storage (``ContinuityTable.keys``
+is already one 128-lane row per pair), so no call repacks the table.
+Whether a kernel runs compiled or interpreted follows the platform
+(`repro.kernels.platform`).
 """
 
 from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -47,14 +47,8 @@ def priority_table(cfg: ContinuityConfig) -> np.ndarray:
     return prio
 
 
-def table_rows(table: ContinuityTable) -> jnp.ndarray:
-    """Flatten main key storage into contiguous per-pair rows (P, SLOTS*KL)."""
-    P, S, KL = table.keys.shape
-    return table.keys.reshape(P, S * KL)
-
-
 def probe_table(cfg: ContinuityConfig, table: ContinuityTable, keys,
-                *, interpret: bool = True, use_kernel: bool = True,
+                *, use_kernel: bool = True,
                 qblock: int = 8, use_fp: bool = False):
     """Probe the main segments of ``table`` for a batch of keys.
 
@@ -67,23 +61,22 @@ def probe_table(cfg: ContinuityConfig, table: ContinuityTable, keys,
     from repro.core import continuity as ch  # local import to avoid cycle
     keys = jnp.asarray(keys, jnp.uint32).reshape(-1, KEY_LANES)
     pair, parity = ch.locate(cfg, keys)
-    rows = table_rows(table)
-    ind = table.indicator[:, None]
     prio = jnp.asarray(priority_table(cfg))
     fps = table.fp if use_fp else None
     qfp = ch.fingerprint(keys) if use_fp else None
     if use_kernel:
         match, empty = _probe.probe_segments(
-            rows, ind, prio, pair, parity, keys, fps, qfp,
-            interpret=interpret, qblock=qblock)
+            table.keys, table.indicator, prio, pair, parity, keys, fps, qfp,
+            qblock=qblock)
     else:
-        match, empty = _probe_ref.probe_ref(rows, ind, prio, pair, parity,
-                                            keys, fps, qfp)
+        match, empty = _probe_ref.probe_ref(table.keys, table.indicator,
+                                            prio, pair, parity, keys, fps,
+                                            qfp)
     return match, empty, pair, parity
 
 
 def mutation_plan(cfg: ContinuityConfig, table: ContinuityTable, keys,
-                  *, interpret: bool = True, use_kernel: bool = True,
+                  *, use_kernel: bool = True,
                   qblock: int = 8):
     """Resolve the main-segment mutation plan for a batch of keys.
 
@@ -101,16 +94,13 @@ def mutation_plan(cfg: ContinuityConfig, table: ContinuityTable, keys,
     from repro.core import continuity as ch  # local import to avoid cycle
     keys = jnp.asarray(keys, jnp.uint32).reshape(-1, KEY_LANES)
     pair, parity = ch.locate(cfg, keys)
-    rows = table_rows(table)
-    ind = table.indicator[:, None]
     prio = jnp.asarray(priority_table(cfg))
     qfp = ch.fingerprint(keys)
+    args = (table.keys, table.indicator, table.fp, prio, pair, parity, keys,
+            qfp)
     if use_kernel:
-        return _mutate.mutate_segments(rows, ind, table.fp, prio, pair,
-                                       parity, keys, qfp,
-                                       interpret=interpret, qblock=qblock)
-    return _mutate_ref.mutate_ref(rows, ind, table.fp, prio, pair, parity,
-                                  keys, qfp)
+        return _mutate.mutate_segments(*args, qblock=qblock)
+    return _mutate_ref.mutate_ref(*args)
 
 
 def fp_filter_stats(cfg: ContinuityConfig, table: ContinuityTable, keys):
@@ -148,7 +138,7 @@ def fp_filter_stats(cfg: ContinuityConfig, table: ContinuityTable, keys):
 
 
 def probe_lookup(cfg: ContinuityConfig, table: ContinuityTable, keys,
-                 *, interpret: bool = True, use_kernel: bool = True,
+                 *, use_kernel: bool = True,
                  qblock: int = 8, use_fp: bool = True):
     """Full continuity lookup with the Pallas kernel as the main-segment
     probe stage; byte-identical to ``repro.core.continuity.lookup``.
@@ -162,11 +152,11 @@ def probe_lookup(cfg: ContinuityConfig, table: ContinuityTable, keys,
     from repro.core import continuity as ch
     keys = jnp.asarray(keys, jnp.uint32).reshape(-1, KEY_LANES)
     match, _, pair, parity = probe_table(
-        cfg, table, keys, interpret=interpret, use_kernel=use_kernel,
-        qblock=qblock, use_fp=use_fp)
+        cfg, table, keys, use_kernel=use_kernel, qblock=qblock,
+        use_fp=use_fp)
     found_main = match >= 0
     safe_m = jnp.maximum(match, 0)
-    vals_main = table.vals[pair, safe_m]
+    vals_main = ch.row_get(table.vals, pair, safe_m)
 
     # extension tail: slots S..S+E-1, ascending for BOTH parities (probe
     # order puts them last), only addressable when the pair is extended
@@ -176,13 +166,13 @@ def probe_lookup(cfg: ContinuityConfig, table: ContinuityTable, keys,
     if E:
         ebits = (table.indicator[pair][:, None]
                  >> (S + jnp.arange(E, dtype=jnp.uint32))[None]) & jnp.uint32(1)
-        ekeys = table.ext_keys[jnp.maximum(eidx, 0)]   # (B, E, KL)
+        safe_e = jnp.maximum(eidx, 0)
+        ekeys = ch.row_slots(table.ext_keys[safe_e], E)   # (B, E, KL)
         ematch = has_ext[:, None] & (ebits == 1) & \
             jnp.all(ekeys == keys[:, None, :], axis=-1)
         efound = jnp.any(ematch, axis=-1)
         efirst = jnp.argmax(ematch, axis=-1)
-        evals = jnp.take_along_axis(
-            table.ext_vals[jnp.maximum(eidx, 0)], efirst[:, None, None], 1)[:, 0]
+        evals = ch.row_get(table.ext_vals, safe_e, efirst)
     else:
         efound = jnp.zeros_like(found_main)
         efirst = jnp.zeros(keys.shape[0], jnp.int32)
@@ -194,26 +184,14 @@ def probe_lookup(cfg: ContinuityConfig, table: ContinuityTable, keys,
     values = jnp.where(found_main[:, None], vals_main,
                        jnp.where(efound[:, None], evals, 0))
     reads = 1 + (has_ext & ~found_main).astype(jnp.int32)
-    if cfg.stash_slots:
-        # stash tail: one contiguous region fetch iff the pair's count byte
-        # is non-zero and both main and extension missed (mirrors ch.lookup)
-        found_me = found
-        home = pair.astype(jnp.uint32) + jnp.uint32(1)
-        smatch = (table.stash_meta[None, :] == home[:, None]) & jnp.all(
-            table.stash_keys[None, :, :] == keys[:, None, :], axis=-1)
-        sfound = jnp.any(smatch, axis=-1) & ~found
-        sfirst = jnp.argmax(smatch, axis=-1).astype(jnp.int32)
-        values = jnp.where(sfound[:, None], table.stash_vals[sfirst], values)
-        slot = jnp.where(sfound, cfg.total_bits + sfirst, slot)
-        found = found | sfound
-        reads = reads + ((ch.stash_count(table, pair) > 0)
-                         & ~found_me).astype(jnp.int32)
+    if cfg.stash_slots:                        # the same stash stage as ch.lookup
+        found, values, slot, reads = ch._stash_tail(
+            cfg, table, keys, pair, found, values, slot, reads)
     return ch.LookupResult(found, values, slot, pair, reads)
 
 
 def paged_attention(q, kpool, vpool, page_table, seq_lens, *,
-                    scale: float | None = None, interpret: bool = True,
-                    use_kernel: bool = True):
+                    scale: float | None = None, use_kernel: bool = True):
     """Paged GQA decode attention; pads the q-head group dim to >=8 sublanes
     so the kernel block shapes are TPU-tileable, then unpads."""
     if not use_kernel:
@@ -230,7 +208,7 @@ def paged_attention(q, kpool, vpool, page_table, seq_lens, *,
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, pad), (0, 0)))
         q = qg.reshape(B, KVH * (G + pad), D)
     out = _pa.paged_attention(q, kpool, vpool, page_table, seq_lens,
-                              scale=scale, interpret=interpret)
+                              scale=scale)
     if pad:
         out = out.reshape(B, KVH, G + pad, D)[:, :, :G].reshape(B, H, D)
     return out

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import pallas_call
+
 NEG_INF = -1e30
 
 
@@ -76,9 +78,9 @@ def _paged_attn_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale",))
 def paged_attention(q, kpool, vpool, page_table, seq_lens, *,
-                    scale: float | None = None, interpret: bool = True):
+                    scale: float | None = None):
     """Paged GQA decode attention.
 
     Args:
@@ -117,11 +119,10 @@ def paged_attention(q, kpool, vpool, page_table, seq_lens, *,
         ],
     )
     kernel = functools.partial(_paged_attn_kernel, page_size=PS, scale=scale)
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, D), q.dtype),
-        interpret=interpret,
     )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
       qg, kpool, vpool)
     return out.reshape(B, H, D)

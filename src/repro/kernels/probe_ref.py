@@ -15,8 +15,10 @@ def probe_ref(rows: jnp.ndarray, indicators: jnp.ndarray, prio: jnp.ndarray,
     """Reference segment probe.
 
     Args:
-      rows:       (P, SLOTS*KL) uint32 — flattened contiguous segment-pair rows
-      indicators: (P, 1) uint32
+      rows:       (P, R) uint32 — contiguous segment-pair key rows, slot s at
+                  lanes [s*KL, (s+1)*KL), R >= SLOTS*KL (the table pads
+                  rows to 128 lanes)
+      indicators: (P,) or (P, 1) uint32
       prio:       (2, SLOTS) int32 probe rank per parity (BIG = not a candidate)
       pairs:      (B,) int32 — home pair per query
       parity:     (B,) int32
@@ -29,12 +31,11 @@ def probe_ref(rows: jnp.ndarray, indicators: jnp.ndarray, prio: jnp.ndarray,
     Returns:
       match_slot (B,) int32 (-1 = miss), empty_slot (B,) int32 (-1 = full)
     """
-    P, RL = rows.shape
     B, KL = qkeys.shape
-    S = RL // KL
-    seg = rows[pairs].reshape(B, S, KL)
+    S = prio.shape[1]
+    seg = rows[pairs][:, :S * KL].reshape(B, S, KL)
     eq = jnp.all(seg == qkeys[:, None, :], axis=-1)
-    ind = indicators[pairs, 0]
+    ind = indicators.reshape(-1)[pairs]
     bits = (ind[:, None] >> jnp.arange(S, dtype=U32)[None]) & U32(1)
     if fps is not None:
         s = jnp.arange(S)

@@ -418,7 +418,7 @@ def lower_kv_cell(shape_name: str, multi_pod: bool):
         table=ch.ContinuityConfig(num_buckets=1 << 22, ext_frac=0.0),
         num_shards=dp,
         axis_names=("pod", "data") if multi_pod else ("data",))
-    table_s = jax.eval_shape(lambda: D.create_sharded(scfg))
+    table_s = jax.eval_shape(lambda: D.create_sharded(scfg, mesh))
     B = 4096 * dp
     keys_s = jax.ShapeDtypeStruct((B, 4), jnp.uint32)
     vals_s = jax.ShapeDtypeStruct((B, 4), jnp.uint32)

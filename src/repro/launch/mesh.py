@@ -4,15 +4,7 @@ touches jax device initialization."""
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 wants explicit axis types; 0.4.x has no AxisType at all
-    from jax.sharding import AxisType
-
-    def _axis_kwargs(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # pre-AxisType jax: every axis is implicitly "auto"
-    def _axis_kwargs(n: int) -> dict:
-        return {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -27,9 +19,9 @@ def make_production_mesh(*, multi_pod: bool = False):
         shape, axes = (2, 16, 16), ("pod", "data", "model")
     else:
         shape, axes = (16, 16), ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for fake-device tests (device count must already allow it)."""
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

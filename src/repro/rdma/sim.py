@@ -117,8 +117,9 @@ def run_ycsb(scheme: str, workload: str, *, num_records: int = 3000,
     rng = np.random.RandomState(seed)
     K = ycsb.make_key(np.arange(num_records))
     V = ycsb.make_value(rng, num_records)
-    table, res = store.insert(store.create(), K, V)
-    loaded = np.flatnonzero(np.asarray(res.ok))     # read only resident keys
+    # load in the same bounded, shape-stable batches the rounds use
+    table, load_ok = api.bulk_load(store, store.create(), K, V, batch=batch)
+    loaded = np.flatnonzero(load_ok)                # read only resident keys
     zipf = ycsb.Zipf(len(loaded))
     # YCSB scrambles zipfian ranks over the keyspace: popularity must be
     # independent of insertion order (rank==id would make the hottest keys

@@ -244,3 +244,15 @@ def test_custom_scheme_registration_roundtrip():
     finally:
         from repro.api import registry as _r
         _r._REGISTRY.pop("dense_quarter", None)
+
+
+@pytest.mark.parametrize("scheme", api.available_schemes())
+def test_bulk_load_matches_one_shot_insert(scheme):
+    """Loading in bounded, masked batches gives the one-shot table."""
+    store = api.make_store(scheme, table_slots=SLOTS)
+    K, V = keys_vals(N + 7)
+    one, res = store.insert(store.create(), K, V)
+    bulk, ok = api.bulk_load(store, store.create(), K, V, batch=64)
+    np.testing.assert_array_equal(ok, np.asarray(res.ok))
+    for a, b in zip(jax.tree.leaves(one), jax.tree.leaves(bulk)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

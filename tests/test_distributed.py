@@ -37,7 +37,7 @@ from repro.launch.mesh import make_debug_mesh
 mesh = make_debug_mesh((4, 2), ("data", "model"))
 scfg = D.StoreConfig(table=ch.ContinuityConfig(num_buckets=256, ext_frac=0.0),
                      num_shards=4)
-table = D.create_sharded(scfg)
+table = D.create_sharded(scfg, mesh)
 lookup = D.make_lookup(scfg, mesh)
 write = D.make_write(scfg, mesh)
 rng = np.random.RandomState(0)
@@ -72,7 +72,7 @@ from repro.launch.mesh import make_debug_mesh
 mesh = make_debug_mesh((8,), ("data",))
 tcfg = ch.ContinuityConfig(num_buckets=512, ext_frac=0.0)
 scfg = D.StoreConfig(table=tcfg, num_shards=8)
-dt = D.create_sharded(scfg)
+dt = D.create_sharded(scfg, mesh)
 write = D.make_write(scfg, mesh)
 lookup = D.make_lookup(scfg, mesh)
 lt = ch.create(tcfg)

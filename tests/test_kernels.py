@@ -14,7 +14,8 @@ BIG = 0x7FFFFFFF
 
 
 def make_probe_case(rng, P, S, KL, B, planted_frac=0.5):
-    rows = rng.randint(0, 2 ** 31, size=(P, S * KL)).astype(np.uint32)
+    # key rows padded to a whole 128-lane tile row, as the table stores them
+    rows = rng.randint(0, 2 ** 31, size=(P, 128)).astype(np.uint32)
     ind = rng.randint(0, 2 ** S if S < 31 else 2 ** 31,
                       size=(P, 1)).astype(np.uint32)
     seg = (S * 4) // 5

@@ -170,6 +170,33 @@ def test_residual_waves_bounded_by_contended_cohort():
     assert int(all_waves) >= int(num_waves)
 
 
+@pytest.mark.parametrize("op", ["update", "delete"])
+def test_zipf_hot_keys_match_serial(op):
+    """YCSB-A skew: a zipf(0.99) batch repeats its hottest key dozens of
+    times and holds more duplicate-target ops than one residual trip
+    takes (RESIDUAL_WIDTH), so waves run deep and split across trips."""
+    cfg = _cfg(num_buckets=2048)
+    n, batch = 3000, 2048
+    kb, vb = keys_vals(np.arange(n))
+    table = ch.create(cfg)
+    table, okb, _ = ch.insert(cfg, table, kb, vb)
+    assert bool(okb.all())
+    rng = np.random.RandomState(7)
+    ids = ycsb.Zipf(n).sample(rng, batch)
+    _, counts = np.unique(ids, return_counts=True)
+    assert counts.max() > 50 and counts[counts > 1].sum() > ch.RESIDUAL_WIDTH
+    keys, vals = keys_vals(ids, seed=3)
+    if op == "update":
+        ts, oks, cs = ch.update_serial(cfg, table, keys, vals)
+        tf, okf, cf = ch.update(cfg, table, keys, vals)
+    else:
+        ts, oks, cs = ch.delete_serial(cfg, table, keys)
+        tf, okf, cf = ch.delete(cfg, table, keys)
+    assert table_diff(ts, tf) is None
+    assert bool((oks == okf).all())
+    assert int(cs.pm_writes) == int(cf.pm_writes)
+
+
 # ---------------------------------------------------------------------------
 # ExecPolicy: mutate/use_fp knobs through the store API
 # ---------------------------------------------------------------------------

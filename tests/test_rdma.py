@@ -277,3 +277,20 @@ def test_scheduler_step_is_the_doorbell_flush_boundary():
     assert batcher.transport.doorbells >= steps
     # every translation is one verb; batch x max_pages lanes per step
     assert batcher.transport.total_verbs > 0
+
+
+def test_run_ycsb_loads_in_bounded_batches(monkeypatch):
+    """The sim's load goes through `api.bulk_load`: every insert call has
+    the round's batch shape, however many records are loaded."""
+    shapes = []
+    orig = api.ContinuityStore.insert
+
+    def spy(self, table, keys, vals, mask=None):
+        shapes.append(np.shape(keys))
+        return orig(self, table, keys, vals, mask)
+
+    monkeypatch.setattr(api.ContinuityStore, "insert", spy)
+    out = sim.run_ycsb("continuity", "C", num_records=300, num_ops=128,
+                       batch=64)
+    assert len(shapes) == 5 and set(shapes) == {(64, 4)}
+    assert out["ops_per_s"] > 0
