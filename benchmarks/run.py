@@ -37,9 +37,6 @@ Prints ``name,us_per_call,derived`` CSV. Sections:
   * crash_consistency — recovery work per scheme from the crash/scheme
                     matrix (repro.consistency; EXPERIMENTS.md §Crash)
   * bench_serving — technique-on-the-hot-path serving numbers
-  * roofline      — per-(arch x shape x mesh) dry-run roofline rows
-                    (requires experiments/dryrun/*.json from
-                    ``python -m repro.launch.dryrun --all``)
 
 The serial-vs-wave write-batch sweep always runs and is written to
 ``--bench-json`` (default BENCH_hash.json; ops/s + PM-write counters at
@@ -59,12 +56,12 @@ import json
 HASH_SECTIONS = ("pm_writes", "access_amp", "search", "update_micro",
                  "ycsb", "end_to_end", "load_factor", "resize")
 SECTIONS = HASH_SECTIONS + ("cluster", "cache", "obs", "crash_consistency",
-                            "hash", "serving", "roofline")
+                            "hash", "serving")
 
 
 def main(argv=None) -> None:
     p = argparse.ArgumentParser(description=__doc__)
-    p.add_argument("--sections", default="hash,serving,roofline",
+    p.add_argument("--sections", default="hash,serving",
                    help="comma-separated subset of "
                         f"{', '.join(SECTIONS)} "
                         "(the write-batch sweep always runs)")
@@ -98,7 +95,7 @@ def main(argv=None) -> None:
     rows = []
     table1 = crash = e2e = lf = rz = cluster = cache = obs_sec = None
     from benchmarks import (bench_cache, bench_cluster, bench_crash,
-                            bench_hash, bench_obs, bench_serving, roofline)
+                            bench_hash, bench_obs, bench_serving)
     if "pm_writes" in sections:
         table1 = bench_hash.bench_pm_writes(rows)
     if "crash_consistency" in sections:
@@ -125,8 +122,6 @@ def main(argv=None) -> None:
         rz = bench_hash.bench_resize(rows)
     if "serving" in sections:
         bench_serving.run(rows)
-    if "roofline" in sections:
-        roofline.run(rows)
     payload = (bench_hash.bench_write_batch_sweep(rows, batches=batches)
                if batches else {})
     if args.merge:

@@ -28,6 +28,7 @@ from typing import Any, ClassVar, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.api.types import ExecPolicy, OpResult, ResizeState
 from repro.core import continuity as ch
 from repro.core import dense as dn
@@ -44,8 +45,10 @@ from repro.core.continuity import KEY_LANES, VAL_LANES
 def _jit_lookup(store: "_ModuleStore", table, keys):
     from repro.rdma import verbs as rv
     res = store._lookup_res(table, keys)
-    plan = store._mod.lookup_plan(store.cfg, table, keys, res)
-    return res, plan, rv.ledger_from_plan(plan)
+    with jax.named_scope("lookup.plan"):
+        plan = store._mod.lookup_plan(store.cfg, table, keys, res)
+        ledger = rv.ledger_from_plan(plan)
+    return res, plan, ledger
 
 
 @functools.partial(jax.jit, static_argnums=0)
@@ -125,9 +128,15 @@ class _ModuleStore:
         # bucket READs; pfarm: window + chained READs; dense: whole-table
         # READ) and the ledger is derived from the plan — this replaced
         # the four per-scheme hand-tallied ``read_counters`` blocks.
-        res, plan, ledger = _jit_lookup(self, table, keys)
-        return OpResult(ok=res.found, ledger=ledger,
-                        values=res.values, reads=res.reads, plan=plan)
+        #
+        # ``api.lookup`` spans the call; ``api.lookup.launch`` the jitted
+        # call alone: static-argument hashing, argument flattening, the
+        # executable's launch and its output-buffer allocation.
+        with obs.device_span("api.lookup"):
+            with obs.device_span("api.lookup.launch"):
+                res, plan, ledger = _jit_lookup(self, table, keys)
+            return OpResult(ok=res.found, ledger=ledger,
+                            values=res.values, reads=res.reads, plan=plan)
 
     def scan_plan(self, table, keys, spans):
         """Verb plan of a YCSB-E short-scan batch: ``spans[i]`` records

@@ -395,19 +395,21 @@ def lookup(cfg: ContinuityConfig, table: ContinuityTable,
     """Batched client read: ONE contiguous segment fetch per key (+1 iff the
     pair has added SBuckets and the main segment missed, +1 iff the pair's
     stash count byte is non-zero and both main and extension missed)."""
-    keys = jnp.asarray(keys, U32).reshape(-1, KEY_LANES)
-    pair, parity = locate(cfg, keys)
-    f = jnp.zeros((keys.shape[0],), jnp.bool_)
-    cand, ckeys, cvals, valid, _, is_ext, has_ext = _gather_candidates(
-        cfg, table, pair, parity, ext_allowed=f)
-    match = valid & jnp.all(ckeys == keys[:, None, :], axis=-1)
-    found = jnp.any(match, axis=-1)
-    first = jnp.argmax(match, axis=-1)                       # probe-priority
-    slot = jnp.where(found, jnp.take_along_axis(cand, first[:, None], 1)[:, 0], -1)
-    values = jnp.take_along_axis(cvals, first[:, None, None], 1)[:, 0]
-    values = jnp.where(found[:, None], values, 0)
-    found_main = jnp.any(match & ~is_ext, axis=-1)
-    reads = 1 + (has_ext & ~found_main).astype(I32)
+    with jax.named_scope("lookup.probe"):
+        keys = jnp.asarray(keys, U32).reshape(-1, KEY_LANES)
+        pair, parity = locate(cfg, keys)
+        f = jnp.zeros((keys.shape[0],), jnp.bool_)
+        cand, ckeys, cvals, valid, _, is_ext, has_ext = _gather_candidates(
+            cfg, table, pair, parity, ext_allowed=f)
+        match = valid & jnp.all(ckeys == keys[:, None, :], axis=-1)
+        found = jnp.any(match, axis=-1)
+        first = jnp.argmax(match, axis=-1)                   # probe-priority
+        slot = jnp.where(found,
+                         jnp.take_along_axis(cand, first[:, None], 1)[:, 0], -1)
+        values = jnp.take_along_axis(cvals, first[:, None, None], 1)[:, 0]
+        values = jnp.where(found[:, None], values, 0)
+        found_main = jnp.any(match & ~is_ext, axis=-1)
+        reads = 1 + (has_ext & ~found_main).astype(I32)
     if cfg.stash_slots:
         found, values, slot, reads = _stash_tail(cfg, table, keys, pair,
                                                  found, values, slot, reads)
@@ -420,11 +422,12 @@ def _stash_tail(cfg, table: ContinuityTable, keys, pair, found, values, slot,
     one dependent stash READ iff the pair's count byte is non-zero and the
     key missed; probe priority stays main > extension > stash (commits
     clear the stash entry LAST)."""
-    srd = (stash_count(table, pair) > 0) & ~found
-    sfound, sidx = _stash_find(cfg, table, keys, pair, ~found)
-    values = jnp.where(sfound[:, None], table.stash_vals[sidx], values)
-    slot = jnp.where(sfound, cfg.total_bits + sidx, slot)
-    return found | sfound, values, slot, reads + srd.astype(I32)
+    with jax.named_scope("lookup.stash"):
+        srd = (stash_count(table, pair) > 0) & ~found
+        sfound, sidx = _stash_find(cfg, table, keys, pair, ~found)
+        values = jnp.where(sfound[:, None], table.stash_vals[sidx], values)
+        slot = jnp.where(sfound, cfg.total_bits + sidx, slot)
+        return found | sfound, values, slot, reads + srd.astype(I32)
 
 
 def _stash_find(cfg: ContinuityConfig, table: ContinuityTable, keys, pair,

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -150,40 +151,42 @@ def probe_lookup(cfg: ContinuityConfig, table: ContinuityTable, keys,
     the 12 ext candidates, and stash-enabled geometries get the same
     one-contiguous-fetch stash tail as the reference."""
     from repro.core import continuity as ch
-    keys = jnp.asarray(keys, jnp.uint32).reshape(-1, KEY_LANES)
-    match, _, pair, parity = probe_table(
-        cfg, table, keys, use_kernel=use_kernel, qblock=qblock,
-        use_fp=use_fp)
-    found_main = match >= 0
-    safe_m = jnp.maximum(match, 0)
-    vals_main = ch.row_get(table.vals, pair, safe_m)
+    with jax.named_scope("lookup.probe"):
+        keys = jnp.asarray(keys, jnp.uint32).reshape(-1, KEY_LANES)
+        match, _, pair, parity = probe_table(
+            cfg, table, keys, use_kernel=use_kernel, qblock=qblock,
+            use_fp=use_fp)
+        found_main = match >= 0
+        safe_m = jnp.maximum(match, 0)
+        vals_main = ch.row_get(table.vals, pair, safe_m)
 
-    # extension tail: slots S..S+E-1, ascending for BOTH parities (probe
-    # order puts them last), only addressable when the pair is extended
-    S, E = cfg.slots_per_pair, cfg.ext_slots
-    eidx = table.ext_map[pair]                         # (B,)
-    has_ext = eidx >= 0
-    if E:
-        ebits = (table.indicator[pair][:, None]
-                 >> (S + jnp.arange(E, dtype=jnp.uint32))[None]) & jnp.uint32(1)
-        safe_e = jnp.maximum(eidx, 0)
-        ekeys = ch.row_slots(table.ext_keys[safe_e], E)   # (B, E, KL)
-        ematch = has_ext[:, None] & (ebits == 1) & \
-            jnp.all(ekeys == keys[:, None, :], axis=-1)
-        efound = jnp.any(ematch, axis=-1)
-        efirst = jnp.argmax(ematch, axis=-1)
-        evals = ch.row_get(table.ext_vals, safe_e, efirst)
-    else:
-        efound = jnp.zeros_like(found_main)
-        efirst = jnp.zeros(keys.shape[0], jnp.int32)
-        evals = jnp.zeros_like(vals_main)
+        # extension tail: slots S..S+E-1, ascending for BOTH parities (probe
+        # order puts them last), only addressable when the pair is extended
+        S, E = cfg.slots_per_pair, cfg.ext_slots
+        eidx = table.ext_map[pair]                         # (B,)
+        has_ext = eidx >= 0
+        if E:
+            ebits = (table.indicator[pair][:, None]
+                     >> (S + jnp.arange(E, dtype=jnp.uint32))[None]) \
+                & jnp.uint32(1)
+            safe_e = jnp.maximum(eidx, 0)
+            ekeys = ch.row_slots(table.ext_keys[safe_e], E)   # (B, E, KL)
+            ematch = has_ext[:, None] & (ebits == 1) & \
+                jnp.all(ekeys == keys[:, None, :], axis=-1)
+            efound = jnp.any(ematch, axis=-1)
+            efirst = jnp.argmax(ematch, axis=-1)
+            evals = ch.row_get(table.ext_vals, safe_e, efirst)
+        else:
+            efound = jnp.zeros_like(found_main)
+            efirst = jnp.zeros(keys.shape[0], jnp.int32)
+            evals = jnp.zeros_like(vals_main)
 
-    found = found_main | efound
-    slot = jnp.where(found_main, match,
-                     jnp.where(efound, S + efirst, -1))
-    values = jnp.where(found_main[:, None], vals_main,
-                       jnp.where(efound[:, None], evals, 0))
-    reads = 1 + (has_ext & ~found_main).astype(jnp.int32)
+        found = found_main | efound
+        slot = jnp.where(found_main, match,
+                         jnp.where(efound, S + efirst, -1))
+        values = jnp.where(found_main[:, None], vals_main,
+                           jnp.where(efound[:, None], evals, 0))
+        reads = 1 + (has_ext & ~found_main).astype(jnp.int32)
     if cfg.stash_slots:                        # the same stash stage as ch.lookup
         found, values, slot, reads = ch._stash_tail(
             cfg, table, keys, pair, found, values, slot, reads)

@@ -22,7 +22,10 @@ Instrumented code imports the free functions::
 
 Both no-op (or hit the process-default registry) unless a tracer /
 registry is installed — `obs.scope()` swaps in a fresh pair for traced
-drills and restores on exit.
+drills and restores on exit.  `obs.device_span()` is the device path's
+span: it goes to the JAX profiler while it records
+(``jax.profiler.start_trace``), on the device trace's clock, and never
+to a tracer.
 """
 
 from repro.obs.export import (METRICS_SUFFIX, TRACE_SUFFIX,
@@ -30,14 +33,15 @@ from repro.obs.export import (METRICS_SUFFIX, TRACE_SUFFIX,
                               export_strings, load_export, write_export)
 from repro.obs.metrics import (GROWTH, Counter, Gauge, Histogram,
                                MetricsRegistry, percentiles_from)
-from repro.obs.trace import (Span, TickClock, Tracer, event, get_registry,
-                             get_tracer, install, scope, set_registry, span)
+from repro.obs.trace import (Span, TickClock, Tracer, device_span, event,
+                             get_registry, get_tracer, install, scope,
+                             set_registry, span)
 
 __all__ = [
     "GROWTH", "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "percentiles_from",
-    "Span", "TickClock", "Tracer", "event", "get_registry", "get_tracer",
-    "install", "scope", "set_registry", "span",
+    "Span", "TickClock", "Tracer", "device_span", "event", "get_registry",
+    "get_tracer", "install", "scope", "set_registry", "span",
     "METRICS_SUFFIX", "TRACE_SUFFIX", "chrome_trace_events",
     "export_payloads", "export_strings", "load_export", "write_export",
 ]

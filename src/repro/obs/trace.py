@@ -21,12 +21,22 @@ no tracer is installed — the instrumentation sweep costs nothing in
 untraced runs.  Install with `install(tracer)` or the `scope()` context
 manager (which also swaps in a fresh metrics registry and restores both
 on exit — what tests and the CI drills use).
+
+`device_span()` is the device path's span, on the device trace's clock:
+while the JAX profiler records (``jax.profiler.start_trace``) it is a
+`jax.profiler.TraceAnnotation` in the same ``.xplane.pb`` as the device
+ops, with its attributes as the event's stats; otherwise the shared
+no-op context, its attributes never formatted.  The profiler being on is
+the only switch, and a `Tracer` never sees these spans, so a traced
+simulation's export does not move.
 """
 
 from __future__ import annotations
 
 import contextlib
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.metrics import MetricsRegistry
 
@@ -151,6 +161,14 @@ def span(name: str, **attrs):
     if t is None:
         return _NULL_SPAN
     return t.span(name, **attrs)
+
+
+def device_span(name: str, **attrs):
+    """``with obs.device_span("api.lookup"):`` — a profiler annotation
+    while the JAX profiler records, else a no-op (shared null context)."""
+    if not TraceAnnotation.is_enabled():
+        return _NULL_SPAN
+    return TraceAnnotation(name, **attrs)
 
 
 def event(name: str, **attrs) -> None:

@@ -13,6 +13,7 @@ Three layers:
 """
 
 import dataclasses
+import re
 
 import jax
 import numpy as np
@@ -256,3 +257,70 @@ def test_bulk_load_matches_one_shot_insert(scheme):
     np.testing.assert_array_equal(ok, np.asarray(res.ok))
     for a, b in zip(jax.tree.leaves(one), jax.tree.leaves(bulk)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---------------------------------------------------------------------------
+# the lookup path's own marks: host spans and named scopes
+# ---------------------------------------------------------------------------
+
+LOOKUP_SCOPES = ("lookup.probe", "lookup.stash", "lookup.plan")
+
+
+def _tiny_lookup(probe="gather", n=2048):
+    store = api.make_store("continuity", table_slots=1 << 14).with_policy(
+        api.ExecPolicy(probe=probe))
+    K, V = keys_vals(n=n)
+    table, _ = store.insert(store.create(), K, V)
+    return store, table, jax.numpy.asarray(K[:256])
+
+
+def _profiled(tmp_path, fn):
+    import glob
+    import os
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    return jax.profiler.ProfileData.from_file(path)
+
+
+def _host_events(pd, prefix):
+    return sorted(((e.name, e.start_ns, e.end_ns, dict(e.stats))
+                   for p in pd.planes if not p.name.startswith("/device:")
+                   for line in p.lines for e in line.events
+                   if e.name.startswith(prefix)), key=lambda e: e[1])
+
+
+def test_lookup_writes_its_spans_into_the_profiler_trace(tmp_path):
+    """``api.lookup`` spans the call and holds ``api.lookup.launch``, the
+    jitted call; neither carries an attribute, and a lookup made while
+    the profiler is off writes neither."""
+    store, table, keys = _tiny_lookup()
+    jax.block_until_ready(store.lookup(table, keys).ok)   # compiled here
+    pd = _profiled(tmp_path, lambda: jax.block_until_ready(
+        [store.lookup(table, keys).ok for _ in range(3)]))
+    outer = [e for e in _host_events(pd, "api.") if e[0] == "api.lookup"]
+    launch = [e for e in _host_events(pd, "api.")
+              if e[0] == "api.lookup.launch"]
+    assert len(outer) == len(launch) == 3
+    for (_, s0, e0, a0), (_, s1, e1, a1) in zip(outer, launch):
+        assert s0 <= s1 < e1 <= e0
+        assert a0 == a1 == {}
+
+
+@pytest.mark.parametrize("probe", ["gather", "pallas"])
+def test_lookup_program_carries_its_scopes(probe):
+    """The compiled lookup names each op's phase in its ``op_name``: the
+    probe (gather or Pallas kernel), the stash scan and the verb plan."""
+    from repro.api import stores
+    store, table, keys = _tiny_lookup(probe)
+    text = stores._jit_lookup.lower(store, table, keys).compile().as_text()
+    names = [n.split("/") for n in re.findall(r'op_name="([^"]*)"', text)]
+    for scope in LOOKUP_SCOPES:
+        assert any(scope in n for n in names), scope
+    # the stash's chunk loop is inside its scope, and the scopes do not nest
+    assert any("lookup.stash" in n and "while" in n for n in names)
+    assert not any(sum(s in n for s in LOOKUP_SCOPES) > 1 for n in names)

@@ -7,6 +7,9 @@ fit its memory fails here at no chip time.  The topology is described
 inside a fixture, never at import, and skips where it cannot be.
 """
 
+import os
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -99,10 +102,15 @@ def test_kernels_interpret_on_cpu_untold():
 
 @pytest.mark.parametrize("env", [None, "/somewhere/else"])
 def test_compile_cache_dir(monkeypatch, env):
-    """Entry points cache compiles where JAX_COMPILATION_CACHE_DIR says,
-    setting nothing, else in ``.jax_cache/`` inside the checkout."""
+    """On an accelerator, entry points cache compiles where
+    JAX_COMPILATION_CACHE_DIR says, setting no directory, else in
+    ``.jax_cache/`` inside the checkout; the program's metadata is part
+    of the key either way."""
     from repro.runtime import compile_cache
     before = jax.config.jax_compilation_cache_dir
+    meta = jax.config.jax_compilation_cache_include_metadata_in_key
+    canon = jax.config.jax_hlo_source_file_canonicalization_regex
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     if env is None:
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     else:
@@ -116,5 +124,34 @@ def test_compile_cache_dir(monkeypatch, env):
         else:
             assert got == env
             assert jax.config.jax_compilation_cache_dir == before
+        assert jax.config.jax_enable_compilation_cache
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        here = os.path.abspath(compile_cache.__file__)
+        assert re.sub(jax.config.jax_hlo_source_file_canonicalization_regex,
+                      "", here) == "src/repro/runtime/compile_cache.py"
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          meta)
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          canon)
+
+
+@pytest.mark.parametrize("env", [None, "/somewhere/else"])
+def test_compile_cache_off_on_the_cpu(monkeypatch, env):
+    """On the CPU the persistent cache is off, even where
+    JAX_COMPILATION_CACHE_DIR names one, and no directory is set."""
+    from repro.runtime import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    enabled = jax.config.jax_enable_compilation_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    try:
+        assert compile_cache.enable_compile_cache() is None
+        assert not jax.config.jax_enable_compilation_cache
+        assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)

@@ -272,3 +272,67 @@ def test_slo_burn_counts_under_tiny_slo_and_not_at_defaults():
         assert cluster.maintenance["steps"] >= 1
         assert cluster.maintenance["slo_burns"] == 0
         assert reg.counter("maintenance.slo_burn").value == 0
+
+
+# ---------------------------------------------------------------------------
+# spans on the device trace's clock: the JAX profiler
+# ---------------------------------------------------------------------------
+
+def _profiled(tmp_path, fn, prefix="t."):
+    import glob
+    import os
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    pd = jax.profiler.ProfileData.from_file(path)
+    return {e.name: dict(e.stats) for p in pd.planes
+            if not p.name.startswith("/device:")
+            for line in p.lines for e in line.events
+            if e.name.startswith(prefix)}
+
+
+def test_spans_go_to_the_profiler_when_no_tracer_records(tmp_path):
+    """`obs.device_span` goes to the recording profiler, with its
+    attributes, whether or not a tracer is installed, and never to the
+    tracer; `obs.span` goes to the tracer alone."""
+    def body():
+        with obs.span("t.span", n=3):
+            with obs.device_span("t.device", out_buffers=15):
+                pass
+        with obs.scope() as (tracer, _):
+            with obs.span("t.recorded"):
+                with obs.device_span("t.device_only"):
+                    pass
+        assert [s.name for s in tracer.spans] == ["t.recorded"]
+    events = _profiled(tmp_path, body)
+    assert events == {"t.device": {"out_buffers": 15}, "t.device_only": {}}
+
+
+def test_spans_cost_no_formatting_while_nothing_records():
+    class Loud:
+        def __str__(self):
+            raise AssertionError("formatted")
+        __repr__ = __format__ = __str__
+
+    import jax
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    with obs.span("t.off", x=Loud()) as a:
+        with obs.device_span("t.off", x=Loud()) as b:
+            assert a is None and b is None
+
+
+def test_tickclock_export_is_the_same_under_the_profiler(tmp_path):
+    """A recording profiler leaves the `Tracer`'s export byte-identical:
+    the lookups the drill makes write their ``api.*`` spans to the
+    profiler alone."""
+    plain = _traced_mini_run(5)
+    profiled = []
+    events = _profiled(tmp_path, lambda: profiled.append(_traced_mini_run(5)),
+                       prefix="api.")
+    assert profiled[0] == plain
+    assert "api.lookup" not in plain[0] and "api.lookup" in events
