@@ -7,8 +7,9 @@ protocol's calling convention, the unified `OpResult`/`CostLedger`, and an
 
   * ``continuity`` — the paper's scheme; `ExecPolicy.engine` selects the
     wave-vectorized mutation engine vs the serial ``lax.scan`` oracle, and
-    `ExecPolicy.probe` selects the pure-jnp gather vs the Pallas segment-
-    probe kernel (vs its jnp reference) for lookups;
+    `ExecPolicy.probe` selects the lookup's read path: by platform (the
+    default: the Pallas lookup kernel on a TPU, the pure-jnp gather on
+    the CPU), or pinned to one of the gather, the kernel, its jnp oracle;
   * ``level``  — Level hashing (OSDI'18), the paper's PM-friendly baseline;
   * ``pfarm``  — P-FaRM-KV (FaRM-KV x RECIPE), the paper's RDMA baseline;
   * ``dense``  — the dense block-table reference (vLLM-style), the
@@ -270,11 +271,12 @@ class ContinuityStore(_ModuleStore):
 
     ``policy.engine``: ``wave`` -> the fused wave-vectorized mutation
     engine; ``serial`` -> the byte-identical ``lax.scan`` reference.
-    ``policy.probe``: ``gather`` -> pure-jnp lookup; ``pallas`` /
-    ``reference`` -> the Pallas segment-probe kernel / its jnp oracle
-    (`repro.kernels.ops.probe_lookup`), fingerprint pre-filter per
-    ``policy.use_fp`` (default on).  ``policy.mutate`` picks the match
-    backend of the fused update/delete the same way (the Pallas
+    ``policy.probe``: ``auto`` -> by platform (`repro.kernels.ops.lookup`);
+    ``gather`` -> pure-jnp lookup; ``pallas`` / ``reference`` -> the
+    Pallas lookup kernel / its jnp oracle
+    (`repro.kernels.ops.probe_lookup`), the oracle's fingerprint
+    pre-filter per ``policy.use_fp`` (default on).  ``policy.mutate``
+    picks the match backend of the fused update/delete (the Pallas
     mutation-plan kernel / its oracle / the jnp gather)."""
 
     cfg: ch.ContinuityConfig = ch.ContinuityConfig(num_buckets=256)
@@ -300,13 +302,15 @@ class ContinuityStore(_ModuleStore):
                                  qblock=self.policy.qblock)
 
     def _lookup_res(self, table, keys):
-        if self.policy.probe == "gather":
+        probe = self.policy.probe
+        if probe == "gather":
             return ch.lookup(self.cfg, table, keys)
         from repro.kernels import ops as K          # deferred: pallas import
-        return K.probe_lookup(
-            self.cfg, table, keys,
-            use_kernel=self.policy.probe == "pallas",
-            qblock=self.policy.qblock, use_fp=self.policy.use_fp)
+        if probe == "auto":
+            return K.lookup(self.cfg, table, keys)
+        return K.probe_lookup(self.cfg, table, keys,
+                              use_kernel=probe == "pallas",
+                              use_fp=self.policy.use_fp)
 
     def _extract(self, table):
         return ch.extract_items(self.cfg, table)

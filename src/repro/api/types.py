@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from repro.core.pmem import CostLedger
 
 ENGINES = ("wave", "serial")
-PROBES = ("gather", "pallas", "reference")
+PROBES = ("auto", "gather", "pallas", "reference")
 MUTATES = ("gather", "pallas", "reference")
 TRANSPORTS = ("none", "sim")
 
@@ -64,18 +64,26 @@ class ExecPolicy:
       single strategy (level, pfarm, dense) accept either value and run
       their one batched path — results are engine-independent by
       construction.
-    * ``probe`` — client-side read strategy for schemes with a kernel:
-      ``"gather"`` (pure-jnp vector gather), ``"pallas"`` (the Pallas
-      segment-probe kernel), ``"reference"`` (the kernel's jnp oracle).
+    * ``probe`` — client-side read strategy for schemes with a kernel.
+      The default, ``"auto"``, resolves by the platform the lookup is
+      lowered for (`repro.kernels.platform`): the Pallas lookup kernel
+      (``kernels/lookup.py``) on a TPU, the pure-jnp vector gather on the
+      CPU.  The explicit values pin one path, for tests and identity
+      checks: ``"gather"`` (the jnp gather), ``"pallas"`` (the lookup
+      kernel; interpreted on the CPU), ``"reference"`` (the kernel's jnp
+      oracle).
     * ``mutate`` — match backend of the fused wave-engine update/delete
       (continuity only): same three values, selecting the mutation-plan
       kernel (``kernels/mutate.py``) / its jnp oracle / the vector
       gather.  Ignored by the serial engine and kernel-less schemes.
-    * ``use_fp`` — fingerprint pre-filter in the probe path (default ON:
-      result-identical — visible slots always carry the correct 2-bit
-      field — and cuts negative-search key compares, paper Figs 7/14).
-      The mutation plan always filters regardless of this knob.
-    * ``qblock`` — queries per Pallas grid step (probe/mutate kernels).
+    * ``use_fp`` — fingerprint pre-filter of the ``"reference"`` probe
+      (default ON: result-identical — visible slots always carry the
+      correct 2-bit field — and cuts negative-search key compares, paper
+      Figs 7/14).  The lookup kernel compares the whole key row it
+      fetches and does not filter; the mutation plan always filters
+      regardless of this knob.
+    * ``qblock`` — queries per grid step of the mutation-plan kernel.
+      The lookup kernel derives its block from the batch.
       Whether a kernel runs compiled or interpreted is not a policy: it
       follows the platform (`repro.kernels.platform`).
     * ``transport`` — which transport host-side drivers attach to the verb
@@ -88,7 +96,7 @@ class ExecPolicy:
     """
 
     engine: str = "wave"
-    probe: str = "gather"
+    probe: str = "auto"
     mutate: str = "gather"
     use_fp: bool = True
     qblock: int = 8

@@ -1,13 +1,14 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``probe_table`` adapts a ``ContinuityTable`` into the probe kernel's layout
-(flat contiguous rows + parity priority table) and returns results identical
-to ``repro.core.continuity.lookup``'s probe stage. ``probe_lookup`` extends
-it to a FULL lookup (values + extension slots + fetch accounting) — it is
-the continuity backend's kernel probe strategy, selected through
-``repro.api.ExecPolicy(probe="pallas")`` instead of per-call kwargs.
-``paged_attention`` is re-exported with TPU-alignment padding for the
-q-head-group dimension.
+``probe_table`` adapts a ``ContinuityTable`` into the segment-probe
+kernel's layout (flat contiguous rows + parity priority table) and
+returns the main-segment probe of ``repro.core.continuity.lookup``.
+``probe_lookup`` is a FULL lookup (values + extension slots + fetch
+accounting) on the lookup kernel (`repro.kernels.lookup`) or its jnp
+oracle, selected through ``repro.api.ExecPolicy(probe="pallas" |
+"reference")``; ``lookup`` is the default policy's read path, the kernel
+on a TPU and the jnp gather elsewhere.  ``paged_attention`` is
+re-exported with TPU-alignment padding for the q-head-group dimension.
 
 The kernels read the table's own row storage (``ContinuityTable.keys``
 is already one 128-lane row per pair), so no call repacks the table.
@@ -24,9 +25,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.continuity import ContinuityConfig, ContinuityTable, KEY_LANES
+from repro.kernels import lookup as _lookup
 from repro.kernels import mutate as _mutate
 from repro.kernels import mutate_ref as _mutate_ref
 from repro.kernels import paged_attn as _pa
+from repro.kernels import platform as _platform
 from repro.kernels import probe as _probe
 from repro.kernels import probe_ref as _probe_ref
 
@@ -139,58 +142,86 @@ def fp_filter_stats(cfg: ContinuityConfig, table: ContinuityTable, keys):
 
 
 def probe_lookup(cfg: ContinuityConfig, table: ContinuityTable, keys,
-                 *, use_kernel: bool = True,
-                 qblock: int = 8, use_fp: bool = True):
-    """Full continuity lookup with the Pallas kernel as the main-segment
-    probe stage; byte-identical to ``repro.core.continuity.lookup``.
+                 *, use_kernel: bool = True, use_fp: bool = True):
+    """Full continuity lookup with a Pallas-path probe stage;
+    byte-identical to ``repro.core.continuity.lookup``.
 
-    The kernel resolves the directional main-segment scan (one contiguous
-    row DMA per query, fingerprint pre-filter folded into the match rank);
-    the rare extension-slot tail (the paper's "+1 fetch iff the pair has
-    added SBuckets and the main segment missed") is a tiny jnp gather over
-    the 12 ext candidates, and stash-enabled geometries get the same
-    one-contiguous-fetch stash tail as the reference."""
+    ``use_kernel``: the lookup kernel (`repro.kernels.lookup`) fetches
+    each query's pair row, value row and extension rows by DMA, matches
+    them and returns the value; it compares the whole fetched key row, so
+    it has no use for the fingerprint word.  Otherwise the kernel's jnp
+    oracle runs: ``probe_ref``'s directional main-segment scan (with the
+    fingerprint pre-filter folded into the match rank when ``use_fp``)
+    and a jnp gather over the extension candidates.  Stash-enabled
+    geometries get the same one-contiguous-fetch stash tail as the
+    reference either way."""
     from repro.core import continuity as ch
     with jax.named_scope("lookup.probe"):
         keys = jnp.asarray(keys, jnp.uint32).reshape(-1, KEY_LANES)
-        match, _, pair, parity = probe_table(
-            cfg, table, keys, use_kernel=use_kernel, qblock=qblock,
-            use_fp=use_fp)
-        found_main = match >= 0
-        safe_m = jnp.maximum(match, 0)
-        vals_main = ch.row_get(table.vals, pair, safe_m)
-
-        # extension tail: slots S..S+E-1, ascending for BOTH parities (probe
-        # order puts them last), only addressable when the pair is extended
-        S, E = cfg.slots_per_pair, cfg.ext_slots
-        eidx = table.ext_map[pair]                         # (B,)
-        has_ext = eidx >= 0
-        if E:
-            ebits = (table.indicator[pair][:, None]
-                     >> (S + jnp.arange(E, dtype=jnp.uint32))[None]) \
-                & jnp.uint32(1)
-            safe_e = jnp.maximum(eidx, 0)
-            ekeys = ch.row_slots(table.ext_keys[safe_e], E)   # (B, E, KL)
-            ematch = has_ext[:, None] & (ebits == 1) & \
-                jnp.all(ekeys == keys[:, None, :], axis=-1)
-            efound = jnp.any(ematch, axis=-1)
-            efirst = jnp.argmax(ematch, axis=-1)
-            evals = ch.row_get(table.ext_vals, safe_e, efirst)
+        pair, parity = ch.locate(cfg, keys)
+        if use_kernel:
+            found, values, slot, reads = _lookup.lookup_probe(
+                table.keys, table.vals, table.ext_keys, table.ext_vals,
+                table.indicator, table.ext_map,
+                jnp.asarray(priority_table(cfg)), pair, parity, keys,
+                ext_slots=cfg.ext_slots)
         else:
-            efound = jnp.zeros_like(found_main)
-            efirst = jnp.zeros(keys.shape[0], jnp.int32)
-            evals = jnp.zeros_like(vals_main)
-
-        found = found_main | efound
-        slot = jnp.where(found_main, match,
-                         jnp.where(efound, S + efirst, -1))
-        values = jnp.where(found_main[:, None], vals_main,
-                           jnp.where(efound[:, None], evals, 0))
-        reads = 1 + (has_ext & ~found_main).astype(jnp.int32)
+            found, values, slot, reads = _probe_oracle(
+                cfg, table, keys, pair, parity, use_fp)
     if cfg.stash_slots:                        # the same stash stage as ch.lookup
         found, values, slot, reads = ch._stash_tail(
             cfg, table, keys, pair, found, values, slot, reads)
     return ch.LookupResult(found, values, slot, pair, reads)
+
+
+def _probe_oracle(cfg: ContinuityConfig, table: ContinuityTable, keys, pair,
+                  parity, use_fp: bool):
+    """The lookup kernel's jnp oracle: (found, values, slot, reads)."""
+    from repro.core import continuity as ch
+    prio = jnp.asarray(priority_table(cfg))
+    fps, qfp = ((table.fp, ch.fingerprint(keys)) if use_fp else (None, None))
+    match, _ = _probe_ref.probe_ref(table.keys, table.indicator, prio, pair,
+                                    parity, keys, fps, qfp)
+    found_main = match >= 0
+    vals_main = ch.row_get(table.vals, pair, jnp.maximum(match, 0))
+
+    # extension tail: slots S..S+E-1, ascending for BOTH parities (probe
+    # order puts them last), only addressable when the pair is extended
+    S, E = cfg.slots_per_pair, cfg.ext_slots
+    eidx = table.ext_map[pair]                             # (B,)
+    has_ext = eidx >= 0
+    if E:
+        ebits = (table.indicator[pair][:, None]
+                 >> (S + jnp.arange(E, dtype=jnp.uint32))[None]) \
+            & jnp.uint32(1)
+        safe_e = jnp.maximum(eidx, 0)
+        ekeys = ch.row_slots(table.ext_keys[safe_e], E)   # (B, E, KL)
+        ematch = has_ext[:, None] & (ebits == 1) & \
+            jnp.all(ekeys == keys[:, None, :], axis=-1)
+        efound = jnp.any(ematch, axis=-1)
+        efirst = jnp.argmax(ematch, axis=-1)
+        evals = ch.row_get(table.ext_vals, safe_e, efirst)
+    else:
+        efound = jnp.zeros_like(found_main)
+        efirst = jnp.zeros(keys.shape[0], jnp.int32)
+        evals = jnp.zeros_like(vals_main)
+
+    found = found_main | efound
+    slot = jnp.where(found_main, match, jnp.where(efound, S + efirst, -1))
+    values = jnp.where(found_main[:, None], vals_main,
+                       jnp.where(efound[:, None], evals, 0))
+    reads = 1 + (has_ext & ~found_main).astype(jnp.int32)
+    return found, values, slot, reads
+
+
+def lookup(cfg: ContinuityConfig, table: ContinuityTable, keys):
+    """The default store's lookup, resolved by the platform it is lowered
+    for: the lookup kernel on a TPU, ``continuity.lookup``'s jnp gather on
+    any other (the CPU, where the kernel would only be interpreted)."""
+    from repro.core import continuity as ch
+    return _platform.by_platform(
+        table, keys, default=lambda t, k: ch.lookup(cfg, t, k),
+        tpu=lambda t, k: probe_lookup(cfg, t, k))
 
 
 def paged_attention(q, kpool, vpool, page_table, seq_lens, *,

@@ -208,14 +208,26 @@ def test_policy_serial_vs_wave_byte_identical():
     _tree_equal(dw, ds)
 
 
-@pytest.mark.parametrize("probe", ["reference", "pallas"])
-def test_policy_probe_strategies_match_gather(probe):
-    n = 96
+@pytest.mark.parametrize("n", [96, 500])
+@pytest.mark.parametrize("probe", ["reference", "pallas", "auto"])
+def test_policy_probe_strategies_match_gather(probe, n):
+    """Every probe strategy answers as the gather does, leaf for leaf.
+    ``n = 96`` leaves the 512-slot table sparse (home-bucket hits, an
+    empty pair); ``n = 500`` fills pairs, extension groups and the stash.
+    The batches: the loaded keys, absent keys, and a mix of 700 that
+    spans two blocks of the lookup kernel."""
+    from repro.core import continuity as ch
     K, V = keys_vals(n=n)
-    gather = api.make_store("continuity", table_slots=512)
+    gather = api.make_store("continuity", table_slots=512).with_policy(
+        api.ExecPolicy(probe="gather"))
     t, _ = gather.insert(gather.create(), K, V)
-    kern = gather.with_policy(api.ExecPolicy(probe=probe, qblock=8))
-    for q in (K, ycsb.negative_keys(np.random.RandomState(2), n, 32)):
+    slot = np.asarray(ch.lookup(gather.cfg, t, K).slot)
+    if n == 500:     # the case reaches extension groups and the stash
+        assert (slot >= gather.cfg.slots_per_pair).any()
+        assert (slot >= gather.cfg.total_bits).any()
+    kern = gather.with_policy(api.ExecPolicy(probe=probe))
+    neg = ycsb.negative_keys(np.random.RandomState(2), n, 32)
+    for q in (K, neg, np.resize(np.concatenate([neg, K]), (700, 4))):
         a = kern.lookup(t, q)
         b = gather.lookup(t, q)
         _tree_equal(a, b)
@@ -311,10 +323,11 @@ def test_lookup_writes_its_spans_into_the_profiler_trace(tmp_path):
         assert a0 == a1 == {}
 
 
-@pytest.mark.parametrize("probe", ["gather", "pallas"])
+@pytest.mark.parametrize("probe", ["gather", "pallas", "auto"])
 def test_lookup_program_carries_its_scopes(probe):
     """The compiled lookup names each op's phase in its ``op_name``: the
-    probe (gather or Pallas kernel), the stash scan and the verb plan."""
+    probe (gather or Pallas kernel; the default policy's, by platform),
+    the stash scan and the verb plan."""
     from repro.api import stores
     store, table, keys = _tiny_lookup(probe)
     text = stores._jit_lookup.lower(store, table, keys).compile().as_text()

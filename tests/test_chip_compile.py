@@ -18,11 +18,13 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import continuity as ch
 from repro.kernels import ops as K
+from repro.kernels.lookup import lookup_probe
 from repro.kernels.mutate import mutate_segments
 from repro.kernels.probe import probe_segments
 from repro.kernels.probe_ref import probe_ref
 
 P, B, S = 2 ** 20, 4096, 20      # real widths: 2^20 pairs, one 4096-op batch
+C_PAIRS, C_EXT = 1_677_722, 167_773   # ycsb-zipf's 2^25-slot table
 
 
 @pytest.fixture(scope="module")
@@ -44,13 +46,18 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("kernel", ["probe", "probe_fp", "mutate"])
+@pytest.mark.parametrize("kernel", ["probe", "probe_fp", "mutate", "lookup"])
 def test_kernel_compiles_for_v5e(one_chip, kernel):
     u32 = lambda *s: _spec(s, jnp.uint32, one_chip)
     i32 = lambda *s: _spec(s, jnp.int32, one_chip)
     rows, ind, prio = u32(P, ch.ROW_LANES), u32(P), i32(2, S)
     pairs, parity, qk, fps, qfp = i32(B), i32(B), u32(B, 4), u32(P, 2), u32(B)
-    if kernel == "mutate":
+    if kernel == "lookup":                # at the benchmark table's widths
+        rows, ext = u32(C_PAIRS, ch.ROW_LANES), u32(C_EXT, ch.ROW_LANES)
+        lowered = lookup_probe.lower(rows, rows, ext, ext, u32(C_PAIRS),
+                                     i32(C_PAIRS), prio, pairs, parity, qk,
+                                     ext_slots=12)
+    elif kernel == "mutate":
         lowered = mutate_segments.lower(rows, ind, fps, prio, pairs, parity,
                                         qk, qfp)
     elif kernel == "probe_fp":
@@ -79,6 +86,34 @@ def test_store_op_memory_bounded_by_batch(one_chip, op):
         lowered = ch.update.lower(cfg, table, kb, kb)
     temp = lowered.compile().memory_analysis().temp_size_in_bytes
     assert temp <= 2 * table_bytes, (temp, table_bytes)
+
+
+def test_default_lookup_takes_the_kernel_on_a_tpu_only(one_chip):
+    """The default store's lookup, compiled for the described TPU, runs
+    the lookup kernel under ``lookup.probe``; compiled for the CPU it
+    holds no kernel at all (the jnp gather).  Nothing but the platform
+    chooses."""
+    from repro import api
+    from repro.api import stores
+    store = api.make_store("continuity", table_slots=2 ** 14)
+    assert store.policy.probe == "auto"
+    shapes = jax.eval_shape(lambda: ch.create(store.cfg))
+    kb = jax.ShapeDtypeStruct((B, ch.KEY_LANES), jnp.uint32)
+
+    def op_names(table, keys):
+        text = stores._jit_lookup.lower(store, table, keys).compile().as_text()
+        return [(re.search(r'op_name="([^"]*)"', line).group(1),
+                 'custom_call_target="tpu_custom_call"' in line)
+                for line in text.splitlines() if 'op_name="' in line]
+
+    on_tpu = op_names(
+        jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip), shapes),
+        _spec(kb.shape, kb.dtype, one_chip))
+    kernels = [n for n, custom in on_tpu if custom]
+    assert kernels and all("lookup.probe" in n.split("/") for n in kernels)
+    # on the CPU: no kernel, compiled or interpreted (its wrapper's ops)
+    assert not any(custom or "jit(lookup_probe)" in n
+                   for n, custom in op_names(shapes, kb))
 
 
 def test_kernels_interpret_on_cpu_untold():

@@ -109,17 +109,101 @@ def test_paged_attention_ignores_dead_pages():
     np.testing.assert_allclose(out, base, rtol=1e-6)
 
 
-def test_probe_table_consistent_with_lookup():
+def _inserted(cfg, n, queries):
+    """``n`` records inserted through the wave engine; ``queries`` keys
+    from id 0 on, so those past ``n`` (and any the table refused) miss."""
     import repro.core.continuity as ch
     from repro.data import ycsb
-    from repro.kernels import probe_table
-    cfg = ch.ContinuityConfig(num_buckets=64)
     t = ch.create(cfg)
-    K = ycsb.make_key(np.arange(120))
-    V = ycsb.make_value(np.random.RandomState(3), 120)
-    t, ok, _ = ch.insert(cfg, t, K, V)
-    match, empty, pair, parity = probe_table(cfg, t, K)
-    res = ch.lookup(cfg, t, K)
+    if n:
+        K = ycsb.make_key(np.arange(n))
+        V = ycsb.make_value(np.random.RandomState(3), n)
+        t, _, _ = ch.insert(cfg, t, K, V)
+    return t, ycsb.make_key(np.arange(queries))
+
+
+def _random_state(cfg, queries):
+    """A table state drawn at random, not built by inserts: random key and
+    value rows, indicator words empty, full or random, an extension group
+    on about half the pairs, and queries planted in their home pair's
+    main and extension slots (visible or not), in two slots at once, in
+    the lanes past a row's slots, beside random keys and the zero key,
+    whose pair has no extension group (stale rows must not match it)."""
+    import repro.core.continuity as ch
+    rng = np.random.RandomState(11)
+    P, PE = cfg.num_pairs, cfg.ext_pool_pairs
+    rows = lambda n: rng.randint(0, 2 ** 31, size=(n, 128)).astype(np.uint32)
+    keys, ext_keys = rows(P), rows(PE)
+    full = (1 << cfg.total_bits) - 1
+    ind = rng.choice([0, full, full ^ 1, 0x5555, 0],
+                     size=P).astype(np.uint32)
+    ind[P // 2:] = rng.randint(0, 2 ** 31, size=P - P // 2) | (1 << 31)
+    ext_map = np.where(rng.rand(P) < 0.5, rng.randint(0, PE, size=P), -1)
+    S, E = cfg.slots_per_pair, cfg.ext_slots
+    q = rng.randint(0, 2 ** 31, size=(queries, 4)).astype(np.uint32)
+    q[-8:] = 0
+    pairs = np.asarray(ch.locate(cfg, jnp.asarray(q))[0])
+    ext_map[pairs[-1]], ind[pairs[-1]] = -1, 0xFFFFFFFF
+
+    def put(row, s, i):
+        row[4 * s:4 * s + 4] = q[i]
+
+    for i, p in enumerate(pairs[:queries * 3 // 4]):
+        e = ext_map[p]
+        if e >= 0 and i % 3 == 0:
+            put(ext_keys[e], rng.randint(0, E), i)
+            if i % 2 == 0:                # in the main row too
+                put(keys[p], rng.randint(0, S), i)
+        elif i % 11 == 0:                 # just past the row's slots
+            put(ext_keys[e] if e >= 0 else keys[p], E if e >= 0 else S, i)
+            ind[p] |= 1 << 31
+        else:
+            s = rng.randint(0, S)
+            put(keys[p], s, i)
+            if i % 7 == 0:                # the same key in a second slot
+                put(keys[p], (s + 3) % S, i)
+    t = ch.create(cfg)._replace(
+        keys=jnp.asarray(keys), vals=jnp.asarray(rows(P)),
+        indicator=jnp.asarray(ind), ext_keys=jnp.asarray(ext_keys),
+        ext_vals=jnp.asarray(rows(PE)),
+        ext_map=jnp.asarray(ext_map.astype(np.int32)))
+    return t, q
+
+
+# (geometry, table, query batch): hits in home buckets, SBuckets,
+# extension groups and the stash; misses; both parities; empty and full
+# pairs; batches that are not a multiple of the kernel's block, one of
+# them over two blocks
+PROBE_CASES = {
+    "sparse": (dict(num_buckets=64), lambda c: _inserted(c, 120, 120)),
+    "full-ext-stash-2blocks": (dict(num_buckets=64, stash_frac=1 / 8),
+                               lambda c: _inserted(c, 700, 760)),
+    "buckets-of-2": (dict(num_buckets=64, bucket_slots=2),
+                     lambda c: _inserted(c, 300, 333)),
+    "empty": (dict(num_buckets=64), lambda c: _inserted(c, 0, 40)),
+    "random-state": (dict(num_buckets=128, ext_frac=0.5),
+                     lambda c: _random_state(c, 600)),
+}
+
+
+@pytest.mark.parametrize("case", list(PROBE_CASES))
+def test_probe_table_consistent_with_lookup(case):
+    """The lookup kernel's path (``probe_lookup``) is byte-identical to
+    ``continuity.lookup`` in all five result leaves, and the segment
+    probe's match is the lookup's slot wherever that slot is a main one."""
+    import repro.core.continuity as ch
+    from repro.kernels import ops as K
+    from repro.kernels import probe_table
+    geometry, fill = PROBE_CASES[case]
+    cfg = ch.ContinuityConfig(**geometry)
+    t, q = fill(cfg)
+    res = ch.lookup(cfg, t, q)
+    got = K.probe_lookup(cfg, t, q, use_kernel=True)
+    for name, a, b in zip(res._fields, got, res):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    match, empty, pair, parity = probe_table(cfg, t, q)
     slot = np.asarray(res.slot)
     main = (slot >= 0) & (slot < cfg.slots_per_pair)
     np.testing.assert_array_equal(np.asarray(match)[main], slot[main])
